@@ -16,6 +16,7 @@ from benchmarks import (channel_bench, contention_bench, faults_bench,
                         fig5_counter_acc, fig6_cw_size, objectives_bench,
                         roofline, kernel_bench, round_bench,
                         sparse_bench, sweep_bench)
+from repro.launch.compile_cache import enable_compile_cache
 
 SUITES = {
     "fig2": fig2_iid.run,
@@ -36,6 +37,7 @@ SUITES = {
 
 
 def main() -> None:
+    enable_compile_cache()
     picks = [a for a in sys.argv[1:] if not a.startswith("-")] or list(SUITES)
     print("name,us_per_call,derived")
     failed = []

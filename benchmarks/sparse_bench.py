@@ -11,7 +11,9 @@ U=1e5, K=64 on CPU.
 
 Each (users, mode) cell runs in a SUBPROCESS so ``ru_maxrss`` reports
 an honest per-config peak (a shared process would carry the largest
-cell's high-water mark into every later reading). Contention itself is
+cell's high-water mark into every later reading). A child cannot take
+an accelerator that the parent holds, so ``run`` refuses to start when
+this process has already initialised a non-CPU backend. Contention itself is
 the device engine (``contention_backend="device"``) for both modes —
 the 1e5+ regimes are exactly what it exists for, and it cancels out of
 the mode comparison. Timed rounds exclude the first (compile) round.
@@ -114,7 +116,22 @@ def _child(users: int, mode: str) -> None:
               sys.stdout)
 
 
+def _assert_chip_free() -> None:
+    """Each cell runs in a child process, and a chip belongs to one
+    process at a time: a child that needs the accelerator fails or
+    hangs while this process holds it. Fail here instead."""
+    from jax._src import xla_bridge
+    if xla_bridge.backends_are_initialized():
+        import jax
+        if jax.default_backend() != "cpu":
+            raise RuntimeError(
+                "the sparse bench runs each cell in a child process, but "
+                f"this process already holds the {jax.default_backend()} "
+                "backend; run `python -m benchmarks.run sparse` on its own")
+
+
 def run():
+    _assert_chip_free()
     lines = []
     grid = SMOKE_GRID if SMOKE else FULL_GRID
     results = []

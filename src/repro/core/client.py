@@ -13,12 +13,15 @@ from repro.core.rngs import client_rng
 from repro.optim.sgd import sgd_update
 
 
-def sgd_epoch_scan(loss_fn: Callable, lr: float) -> Callable:
+def sgd_epoch_scan(loss_fn: Callable, lr: float,
+                   use_kernel: bool = True) -> Callable:
     """Returns ``run(params, batched_data) -> (params, per_batch_losses)``:
-    one SGD step per batch, scanned.
+    one SGD step per batch, scanned. ``use_kernel=False`` keeps the
+    update on its jnp oracle (a Pallas call cannot be partitioned, so a
+    cohort sharded over several devices needs it).
 
     THE local-SGD inner loop — the ragged per-user trainer, the stacked
-    vmap path and the fused cohort round all build on this one closure,
+    vmap path and the fused cohort round all build on this one scan,
     so the three HostBackend paths can't drift apart numerically
     (their winner parity is pinned by ``tests/test_fused_round.py``).
     """
@@ -26,7 +29,7 @@ def sgd_epoch_scan(loss_fn: Callable, lr: float) -> Callable:
     def run(params, batched_data):
         def step(p, batch):
             loss, grads = jax.value_and_grad(loss_fn)(p, batch)
-            return sgd_update(p, grads, lr), loss
+            return sgd_update(p, grads, lr, use_kernel), loss
 
         return jax.lax.scan(step, params, batched_data)
 

@@ -342,13 +342,14 @@ class HostBackend(Backend):
         self._mesh = mesh
         self._shard = shardable(self.num_users, mesh)
         # Pallas under GSPMD needs custom partitioning; when the cohort
-        # actually shards over >1 device, route the fused reductions
-        # through the jnp oracle, which GSPMD partitions on its own.
+        # actually shards over >1 device, route the fused reductions and
+        # the local SGD update through the jnp oracles, which GSPMD
+        # partitions on its own.
         # Single-partition execution (no mesh, 1-long axis, or an
         # unusable mesh) keeps the kernel path.
         self._use_kernel = (not self._shard) or mesh.size == 1
 
-        epoch_run = sgd_epoch_scan(loss_fn, lr)
+        epoch_run = sgd_epoch_scan(loss_fn, lr, self._use_kernel)
         self._epoch_run = epoch_run   # the shared local-SGD inner loop
 
         def train_one(params, batched):
@@ -443,7 +444,8 @@ class HostBackend(Backend):
     def _ensure_obj_run(self):
         if self._obj_run is None:
             self._obj_run = objective_epoch_scan(
-                self._loss_fn, self._lr, self._objective.uses_h)
+                self._loss_fn, self._lr, self._objective.uses_h,
+                self._use_kernel)
         return self._obj_run
 
     def _ensure_obj_h(self, state):
@@ -1093,7 +1095,7 @@ class HostBackend(Backend):
     def _build_sparse(self):
         K = self._k_max
         self._ensure_xstack()
-        nb, epoch_run = self._nb, self._epoch_run
+        nb = self._nb
         obj_on = self.objective_active()
         # objective programs skip the explicit sharding annotations
         # (same rule as the fused path: plain jit, GSPMD propagates)
@@ -1101,9 +1103,10 @@ class HostBackend(Backend):
                  and winner_shardable(K, self._mesh) and not obj_on)
         # same rule as the fused path: Pallas under real GSPMD
         # partitioning needs custom partitioning, so a >1-way K split
-        # routes the reductions through the jnp oracle
+        # routes the reductions and the SGD update through the oracles
         uk = (not shard) or self._mesh.size == 1
         self._sparse_uk = uk
+        epoch_run = sgd_epoch_scan(self._loss_fn, self._lr, uk)
         use_h = obj_on and self._objective.uses_h
         if obj_on:
             obj_run = self._ensure_obj_run()
@@ -1305,14 +1308,16 @@ class HostBackend(Backend):
     def _build_sweep_fns(self, E: int):
         U, uk = self.num_users, self._use_kernel
         self._ensure_xstack()
-        nb, epoch_run = self._nb, self._epoch_run
+        nb = self._nb
         shard = (self._mesh is not None
                  and sweep_shardable(E, U, self._mesh))
         if shard:
             # mirror the fused-path rule: Pallas under real GSPMD
             # partitioning needs custom partitioning, so a >1-way split
-            # routes the reductions through the jnp oracle
+            # routes the reductions and the SGD update through the jnp
+            # oracles
             uk = uk and self._mesh.size == 1
+        epoch_run = sgd_epoch_scan(self._loss_fn, self._lr, uk)
 
         def bcast(g):
             glob = jax.tree.map(
@@ -1386,7 +1391,7 @@ class HostBackend(Backend):
         U = self.num_users
         self._ensure_xstack()
         nb, uk = self._nb, self._use_kernel
-        obj_run = objective_epoch_scan(self._loss_fn, self._lr, use_h)
+        obj_run = objective_epoch_scan(self._loss_fn, self._lr, use_h, uk)
 
         def _tail(trained, losses, glob, need_prio):
             loss_u = losses[:, :, -nb:].mean(axis=2)          # (E, U)
@@ -1820,7 +1825,7 @@ class HostBackend(Backend):
         (``_build_sweep_obj_merge``; jit re-specializes by shape)."""
         self._ensure_xstack()
         nb, uk = self._nb, self._use_kernel
-        obj_run = objective_epoch_scan(self._loss_fn, self._lr, use_h)
+        obj_run = objective_epoch_scan(self._loss_fn, self._lr, use_h, uk)
 
         def lane_prios(tr, g):
             return stacked_model_priorities(tr, g, use_kernel=uk)

@@ -22,8 +22,10 @@ transition needs two full-row reductions first:
                           winner = min expiring index      (row reductions)
   3. ``_transition_kernel`` decrement / deliver / redraw    (elementwise)
 
-Grid: (B, N/BLOCK_N); TPU grid steps run sequentially per core, so the
-(1, 1) per-row accumulators are well-defined across the N-blocks.
+Grid: (B, N/BLOCK_N) over rows laid out as (B, 1, N) so each (1, BLOCK_N)
+row block is TPU-aligned for any B. TPU grid steps run sequentially per
+core, so the per-row accumulators — (B,) outputs held whole in SMEM and
+indexed by the row's program id — are well-defined across the N-blocks.
 
 All slot arithmetic is int32 — counters, redraws and the horizon are
 clamped to ``ref.CONTENTION_BIG`` (2^29) so ``t + step + tx_slots``
@@ -38,6 +40,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.ref import CONTENTION_BIG
 
@@ -55,36 +58,36 @@ def _pad_to_block(n: int) -> int:
 
 # ---------------------------------------------------------------- pass 1
 def _min_kernel(cnt_ref, live_ref, step_ref):
-    j = pl.program_id(1)
+    b, j = pl.program_id(0), pl.program_id(1)
 
     @pl.when(j == 0)
     def _init():
-        step_ref[0, 0] = jnp.int32(CONTENTION_BIG)
+        step_ref[b] = jnp.int32(CONTENTION_BIG)
 
     live = live_ref[...] != 0
     masked = jnp.where(live, cnt_ref[...], jnp.int32(CONTENTION_BIG))
-    step_ref[0, 0] = jnp.minimum(step_ref[0, 0], jnp.min(masked))
+    step_ref[b] = jnp.minimum(step_ref[b], jnp.min(masked))
 
 
 # ---------------------------------------------------------------- pass 2
 def _expiry_kernel(cnt_ref, live_ref, step_ref, nexp_ref, winner_ref, *,
                    sentinel: int):
-    j = pl.program_id(1)
+    b, j = pl.program_id(0), pl.program_id(1)
 
     @pl.when(j == 0)
     def _init():
-        nexp_ref[0, 0] = jnp.int32(0)
-        winner_ref[0, 0] = jnp.int32(sentinel)
+        nexp_ref[b] = jnp.int32(0)
+        winner_ref[b] = jnp.int32(sentinel)
 
     live = live_ref[...] != 0
     # step is the row's masked min, so a live counter expires iff it
     # EQUALS step — no decrement pass needed before the detection
-    exp = live & (cnt_ref[...] == step_ref[0, 0])
-    nexp_ref[0, 0] += jnp.sum(exp.astype(jnp.int32))
+    exp = live & (cnt_ref[...] == step_ref[b])
+    nexp_ref[b] += jnp.sum(exp.astype(jnp.int32))
     col = (j * cnt_ref.shape[1]
            + jax.lax.broadcasted_iota(jnp.int32, exp.shape, 1))
-    winner_ref[0, 0] = jnp.minimum(
-        winner_ref[0, 0],
+    winner_ref[b] = jnp.minimum(
+        winner_ref[b],
         jnp.min(jnp.where(exp, col, jnp.int32(sentinel))))
 
 
@@ -92,9 +95,10 @@ def _expiry_kernel(cnt_ref, live_ref, step_ref, nexp_ref, winner_ref, *,
 def _transition_kernel(cnt_ref, live_ref, dbl_ref, win_ref, rand_ref,
                        step_ref, nexp_ref, ncnt_ref, ndbl_ref, nact_ref,
                        *, max_doublings: int):
+    b = pl.program_id(0)
     live = live_ref[...] != 0
-    step = step_ref[0, 0]
-    nexp = nexp_ref[0, 0]
+    step = step_ref[b]
+    nexp = nexp_ref[b]
     cnt2 = jnp.where(live, cnt_ref[...] - step, cnt_ref[...])
     exp = live & (cnt2 == 0)
     deliver = nexp == 1
@@ -119,47 +123,53 @@ def contention_event_pallas(counters, live, doublings, windows, rand,
     blk = _block(npad)
     grid = (B, npad // blk)
     pad = [(0, 0), (0, npad - N)]
-    cnt = jnp.pad(counters.astype(jnp.int32), pad,
-                  constant_values=CONTENTION_BIG)
-    liv = jnp.pad(live.astype(jnp.int32), pad)
-    dbl = jnp.pad(doublings.astype(jnp.int32), pad)
-    win = jnp.pad(windows.astype(jnp.float32), pad, constant_values=1.0)
-    rnd = jnp.pad(rand.astype(jnp.float32), pad)
+    # rows ride as (B, 1, npad) so a row block's last two dims
+    # (1, blk) span the full middle dim — TPU-aligned for any B
+    def rows(x, fill=0):
+        return jnp.pad(x, pad, constant_values=fill).reshape(B, 1, npad)
 
-    row_blk = pl.BlockSpec((1, blk), lambda i, j: (i, j))
-    acc_blk = pl.BlockSpec((1, 1), lambda i, j: (i, 0))
+    cnt = rows(counters.astype(jnp.int32), CONTENTION_BIG)
+    liv = rows(live.astype(jnp.int32))
+    dbl = rows(doublings.astype(jnp.int32))
+    win = rows(windows.astype(jnp.float32), 1.0)
+    rnd = rows(rand.astype(jnp.float32))
+
+    row_blk = pl.BlockSpec((pl.Squeezed(), 1, blk),
+                           lambda i, j: (i, 0, j))
+    # per-row (B,) scalars live whole in SMEM (a vector store of a
+    # scalar to VMEM does not compile); the kernels index them by row
+    acc = pl.BlockSpec(memory_space=pltpu.SMEM)
     i32 = jnp.int32
+    row_out = jax.ShapeDtypeStruct((B, 1, npad), i32)
 
     step = pl.pallas_call(
         _min_kernel, grid=grid,
-        in_specs=[row_blk, row_blk], out_specs=acc_blk,
-        out_shape=jax.ShapeDtypeStruct((B, 1), i32),
+        in_specs=[row_blk, row_blk], out_specs=acc,
+        out_shape=jax.ShapeDtypeStruct((B,), i32),
         interpret=interpret)(cnt, liv)
 
     nexp, winner = pl.pallas_call(
         functools.partial(_expiry_kernel, sentinel=npad), grid=grid,
-        in_specs=[row_blk, row_blk, acc_blk],
-        out_specs=[acc_blk, acc_blk],
-        out_shape=[jax.ShapeDtypeStruct((B, 1), i32),
-                   jax.ShapeDtypeStruct((B, 1), i32)],
+        in_specs=[row_blk, row_blk, acc],
+        out_specs=[acc, acc],
+        out_shape=[jax.ShapeDtypeStruct((B,), i32),
+                   jax.ShapeDtypeStruct((B,), i32)],
         interpret=interpret)(cnt, liv, step)
 
     ncnt, ndbl, nact = pl.pallas_call(
         functools.partial(_transition_kernel,
                           max_doublings=max_doublings), grid=grid,
         in_specs=[row_blk, row_blk, row_blk, row_blk, row_blk,
-                  acc_blk, acc_blk],
+                  acc, acc],
         out_specs=[row_blk, row_blk, row_blk],
-        out_shape=[jax.ShapeDtypeStruct((B, npad), i32),
-                   jax.ShapeDtypeStruct((B, npad), i32),
-                   jax.ShapeDtypeStruct((B, npad), i32)],
+        out_shape=[row_out, row_out, row_out],
         interpret=interpret)(cnt, liv, dbl, win, rnd, step, nexp)
 
     # padded lanes are dead (live=0), so a winner == sentinel beyond N
     # means "none expiring"; report the numpy-oracle sentinel N instead
-    winner = jnp.minimum(winner[:, 0], jnp.int32(N))
-    return (step[:, 0], nexp[:, 0], winner,
-            ncnt[:, :N], ndbl[:, :N], nact[:, :N] != 0)
+    winner = jnp.minimum(winner, jnp.int32(N))
+    return (step, nexp, winner, ncnt[:, 0, :N], ndbl[:, 0, :N],
+            nact[:, 0, :N] != 0)
 
 
 # ------------------------------------------------------- the event loop
@@ -270,6 +280,7 @@ def device_contend_batch(backoff_slots, window_slots, k_arr,
                          participating, *, entropy: int, call_index: int,
                          tx_slots: int, max_backoff_doublings: int,
                          max_sim_slots: int,
+                         use_kernel: bool = True,
                          interpret: Optional[bool] = None):
     """Run B contention rounds on device; returns ``BatchCSMAResult``.
 
@@ -278,7 +289,8 @@ def device_contend_batch(backoff_slots, window_slots, k_arr,
     the counter-based threefry stream: one base key per simulator, one
     fold per ``contend_batch`` call, one more per medium event — same
     (entropy, call order) => bit-identical results, with zero mutable
-    rng state inside the loop.
+    rng state inside the loop. ``use_kernel=False`` runs the per-event
+    op through its jnp oracle, as every ``kernels.ops`` wrapper does.
     """
     from repro.core.csma import BatchCSMAResult
     from repro.kernels.ops import kernel_mode
@@ -302,7 +314,7 @@ def device_contend_batch(backoff_slots, window_slots, k_arr,
             finish_slots=np.zeros((B, 0), np.int64),
             collisions=z, elapsed_slots=z.copy(), n_delivered=z.copy())
 
-    use_kernel, interp = kernel_mode(True, interpret)
+    use_kernel, interp = kernel_mode(use_kernel, interpret)
     key = jax.random.fold_in(
         jax.random.PRNGKey(int(entropy) & (2 ** 63 - 1)),
         int(call_index))

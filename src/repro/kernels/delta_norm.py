@@ -4,8 +4,10 @@ Eq. 2's distance needs, per layer, both the delta norm and the reference
 norm. Naive jnp lowers to: read w_k, read w, write (w_k - w), read it
 back for the square-reduce, plus a second pass for ||w||^2 — ~5 HBM
 touches. This kernel streams both operands through VMEM once and keeps
-two f32 accumulators in SMEM-resident (1,1) outputs: 2 reads total,
-which matters when w is a terabyte-scale model (DESIGN.md §3).
+the two f32 accumulators in (1, 1) outputs held whole in SMEM, the
+scalar memory (a scalar store to VMEM does not compile for the chip):
+2 reads total, which matters when w is a terabyte-scale model
+(DESIGN.md §3).
 
 Grid: 1-D over row-blocks of the flattened-and-(8,128)-retiled operand.
 TPU grid steps execute sequentially on a core, so accumulating into the
@@ -16,6 +18,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 BLOCK_ROWS = 256  # (256, 128) f32 tile = 128 KiB VMEM per operand
 LANES = 128
@@ -60,8 +63,8 @@ def delta_norm_pallas(w_local, w_global, *, interpret=False):
             pl.BlockSpec((BLOCK_ROWS, LANES), lambda i: (i, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, 1), lambda i: (0, 0)),
-            pl.BlockSpec((1, 1), lambda i: (0, 0)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((1, 1), jnp.float32),

@@ -66,14 +66,19 @@ def gather_combine_pallas(stacked, idx, weights, glob, *,
         n *= d
     x = _retile(stacked, s)                       # (S, cols)
     cols = x.shape[1]
+    # (S, 1, cols) with the row axis squeezed out of the block: the
+    # block's last two dims (1, BLOCK_COLS) then span the array's full
+    # middle dim, which the TPU tiling accepts for any S — a (1, BLOCK)
+    # block over (S, cols) is not 8-row aligned and does not compile
+    x = x.reshape(s, 1, cols)
     g = _retile(glob[None], 1)                    # (1, cols), same padding
     w = weights.reshape(k, 1).astype(jnp.float32)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(cols // BLOCK_COLS, k),
         in_specs=[
-            pl.BlockSpec((1, BLOCK_COLS),
-                         lambda i, j, idx_ref: (idx_ref[j], i)),
+            pl.BlockSpec((pl.Squeezed(), 1, BLOCK_COLS),
+                         lambda i, j, idx_ref: (idx_ref[j], 0, i)),
             pl.BlockSpec((k, 1), lambda i, j, idx_ref: (0, 0)),
             pl.BlockSpec((1, BLOCK_COLS), lambda i, j, idx_ref: (0, i)),
         ],

@@ -30,6 +30,7 @@ from repro.data import (make_classification_dataset, make_token_stream,
 from repro.engine import (ExperimentSpec, FLEngine, PAPER_STRATEGIES,
                           SweepSpec, available_strategies,
                           build_host_engine, make_accuracy_eval)
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.paper_models import get_paper_model
 from repro.models.model import init_params, compute_loss
 from repro.checkpoint import save_checkpoint
@@ -44,7 +45,10 @@ def _spec_from_args(args) -> ExperimentSpec:
         contention_backend=args.contention_backend)
 
 
-def build_paper_engine(args) -> FLEngine:
+def paper_cell(args):
+    """The paper cell from the CLI arguments: ``(spec, init_params,
+    loss_fn, user_data, eval_fn)``, the arguments of
+    ``build_host_engine``."""
     (xtr, ytr), (xte, yte) = make_classification_dataset(
         args.dataset, n_train=args.n_train, n_test=args.n_test,
         seed=args.seed)
@@ -63,8 +67,11 @@ def build_paper_engine(args) -> FLEngine:
 
     eval_fn = make_accuracy_eval(apply_fn, xte, yte)
     params = init_fn(jax.random.PRNGKey(args.seed))
-    return build_host_engine(_spec_from_args(args), params, loss_fn,
-                             user_data, eval_fn)
+    return _spec_from_args(args), params, loss_fn, user_data, eval_fn
+
+
+def build_paper_engine(args) -> FLEngine:
+    return build_host_engine(*paper_cell(args))
 
 
 def build_llm_engine(args) -> FLEngine:
@@ -93,7 +100,7 @@ def build_llm_engine(args) -> FLEngine:
                              user_data, eval_fn)
 
 
-def main():
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--model", default="mlp", choices=["mlp", "cnn"])
     ap.add_argument("--dataset", default="fashion",
@@ -127,7 +134,12 @@ def main():
     ap.add_argument("--out", default=None, help="history JSON path")
     ap.add_argument("--ckpt", default=None, help="final checkpoint path")
     ap.add_argument("--verbose", action="store_true")
-    args = ap.parse_args()
+    return ap
+
+
+def main():
+    enable_compile_cache()
+    args = build_parser().parse_args()
 
     t0 = time.perf_counter()
     engine = (build_llm_engine(args) if args.arch

@@ -32,7 +32,8 @@ register_local(LocalObjective("fedprox", uses_h=False, coeff=lambda s: s.mu))
 register_local(LocalObjective("feddyn", uses_h=True, coeff=lambda s: s.alpha))
 
 
-def objective_epoch_scan(loss_fn: Callable, lr: float, use_h: bool) -> Callable:
+def objective_epoch_scan(loss_fn: Callable, lr: float, use_h: bool,
+                         use_kernel: bool = True) -> Callable:
     """Returns ``run(params, batched_data, glob, prox[, h]) ->
     (params, per_batch_losses)``.
 
@@ -41,7 +42,8 @@ def objective_epoch_scan(loss_fn: Callable, lr: float, use_h: bool) -> Callable:
     sweeps vmap a per-lane (E,) vector over it), ``h`` the per-user
     FedDyn state when ``use_h`` (structural: lanes without h-state in a
     mixed sweep ride the same program with an all-zero h row, which is
-    bitwise free — see module docstring).
+    bitwise free — see module docstring). ``use_kernel`` as in
+    ``sgd_epoch_scan``.
     """
 
     def run(params, batched_data, glob, prox, h=None):
@@ -53,7 +55,7 @@ def objective_epoch_scan(loss_fn: Callable, lr: float, use_h: bool) -> Callable:
                 grads, p, glob)
             if use_h:
                 grads = jax.tree.map(jnp.subtract, grads, h)
-            return sgd_update(p, grads, lr), loss
+            return sgd_update(p, grads, lr, use_kernel), loss
 
         return jax.lax.scan(step, params, batched_data)
 
