@@ -78,6 +78,7 @@ from repro.core.client import Client, batch_epoch, sgd_epoch_scan
 from repro.core.priority import model_priority, stacked_model_priorities
 from repro.core.rngs import client_rng
 from repro.core.server import winner_alphas
+from repro.engine import spans
 from repro.engine.types import TrainResult
 from repro.faults.robust import robust_merge
 from repro.kernels import ops as kops
@@ -1614,29 +1615,27 @@ class HostBackend(Backend):
         n = self.clients[0].num_examples
         take = nb * bs
         perms = np.empty((E, ep, U, take), np.int64)
-        for e in range(E):
-            for k in range(ep):
-                for u in range(U):
-                    perms[e, k, u] = st.rngs[e][u].permutation(n)[:take]
+        with spans.span("fl.round.batches.draw"):
+            for e in range(E):
+                for k in range(ep):
+                    for u in range(U):
+                        perms[e, k, u] = \
+                            st.rngs[e][u].permutation(n)[:take]
         return perms.transpose(0, 2, 1, 3).reshape(E, U, ep * take)
 
     def sweep_batches(self, st: SweepState):
         """(E, U, epochs*nb, bs, ...) round batches, one fancy-index
         over the shared (U, n, ...) data stack (the data is read-only
         and shared; only the index tensor is per-lane)."""
-        E, U = st.num_lanes, self.num_users
-        bs, nb, ep = self._batch_size, self._nb, self._local_epochs
-        big = self._draw_sweep_big(st)
-        rows = np.arange(U)[None, :, None]
-        return jax.tree.map(
-            lambda leaf: leaf[rows, big].reshape(
-                (E, U, ep * nb, bs) + leaf.shape[2:]),
-            self._xstack)
+        rows = np.arange(self.num_users)[None, :, None]
+        return self._gather_sweep_rows(rows, self._draw_sweep_big(st))
 
     def sweep_train(self, st: SweepState, batched,
                     need_priority: bool) -> SweepTrainResult:
         """Dispatch ONE jitted train call for all E lanes; the incoming
         stack is donated into the trained stack (residency chain)."""
+        if spans.on:
+            spans.count("batches.h2d_bytes", spans.host_nbytes(batched))
         stack, st.stack = st.stack, None      # donated below
         if st.obj is not None:
             key = (st.num_lanes, st.obj.use_h)
@@ -1758,9 +1757,10 @@ class HostBackend(Backend):
         tensor."""
         E, R = big_rows.shape[0], big_rows.shape[1]
         bs, nb, ep = self._batch_size, self._nb, self._local_epochs
-        return jax.tree.map(
-            lambda leaf: leaf[rows, big_rows].reshape(
-                (E, R, ep * nb, bs) + leaf.shape[2:]), self._xstack)
+        with spans.span("fl.round.batches.gather"):
+            return jax.tree.map(
+                lambda leaf: leaf[rows, big_rows].reshape(
+                    (E, R, ep * nb, bs) + leaf.shape[2:]), self._xstack)
 
     def _build_sweep_sparse_fns(self, E: int):
         """(bcast_k, round, merge, prepass) jits for E lanes over the
@@ -1923,6 +1923,8 @@ class HostBackend(Backend):
             rows = np.arange(lo, min(lo + C, U))
             batched = self._gather_sweep_rows(rows[None, :, None],
                                               big[:, rows])
+            if spans.on:
+                spans.count("batches.h2d_bytes", spans.host_nbytes(batched))
             if st.obj is None:
                 l, p = fns[3](st.glob, batched)
             elif st.obj.use_h:
@@ -1960,12 +1962,15 @@ class HostBackend(Backend):
             n = self.clients[0].num_examples
             take = nb * bs
             big_rows = np.zeros((E, K, ep * take), np.int64)
-            for e, ws in enumerate(winners_all):
-                for j, u in enumerate(ws):
-                    for k in range(ep):
-                        big_rows[e, j, k * take:(k + 1) * take] = \
-                            st.rngs[e][int(u)].permutation(n)[:take]
+            with spans.span("fl.round.batches.draw"):
+                for e, ws in enumerate(winners_all):
+                    for j, u in enumerate(ws):
+                        for k in range(ep):
+                            big_rows[e, j, k * take:(k + 1) * take] = \
+                                st.rngs[e][int(u)].permutation(n)[:take]
         batched = self._gather_sweep_rows(rows[:, :, None], big_rows)
+        if spans.on:
+            spans.count("batches.h2d_bytes", spans.host_nbytes(batched))
         stack = st.stack if st.stack is not None else fns[0](st.glob)
         st.stack = None
         if st.obj is not None:

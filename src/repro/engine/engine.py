@@ -50,6 +50,7 @@ from repro.checkpoint.fl_state import (generator_state, load_fl_checkpoint,
                                        save_fl_checkpoint)
 from repro.core.counter import FairnessCounter, SweepFairnessCounter
 from repro.core.rngs import channel_noise_entropy, engine_rng, strategy_seed
+from repro.engine import spans
 from repro.engine.backends import Backend, compact_weights
 from repro.engine.registry import create_strategy, select_grouped
 from repro.engine.spec import ExperimentSpec, SweepSpec
@@ -492,25 +493,28 @@ class FLEngine:
         if overlap is None:
             overlap = sweep.overlap
         lanes = [_Lane(spec, self.num_users) for spec in sweep.specs]
-        if getattr(self.backend, "sweep_sparse_capable", lambda: False)():
-            # winner-sparse sweeps run the contention-first lane loop:
-            # every lane selects, then ONE compact (E, K_max, ...) train
-            # call covers all lanes' winners
-            result, _, _ = self._run_lanes_sparse(
-                lanes, init_state=self._init_params, verbose=verbose,
-                labels=sweep.labels, checkpoint_dir=checkpoint_dir)
-            return result
-        if not self.backend.sweep_capable():
+        sparse = getattr(self.backend, "sweep_sparse_capable",
+                         lambda: False)()
+        if not sparse and not self.backend.sweep_capable():
             raise ValueError(
                 "run_sweep needs a sweep-capable backend (HostBackend "
                 "round_mode='fused' or 'sparse' over a rectangular "
                 "cohort); run the cells sequentially through "
                 "FLEngine.run instead")
-        result, _, _ = self._run_lanes(
-            lanes, init_state=self._init_params, overlap=overlap,
-            verbose=verbose, labels=sweep.labels,
-            checkpoint_dir=checkpoint_dir,
-            checkpoint_every=checkpoint_every)
+        with spans.span("fl.sweep", job=spans.next_job()):
+            if sparse:
+                # winner-sparse sweeps run the contention-first lane
+                # loop: every lane selects, then ONE compact
+                # (E, K_max, ...) train call covers all lanes' winners
+                result, _, _ = self._run_lanes_sparse(
+                    lanes, init_state=self._init_params, verbose=verbose,
+                    labels=sweep.labels, checkpoint_dir=checkpoint_dir)
+            else:
+                result, _, _ = self._run_lanes(
+                    lanes, init_state=self._init_params, overlap=overlap,
+                    verbose=verbose, labels=sweep.labels,
+                    checkpoint_dir=checkpoint_dir,
+                    checkpoint_every=checkpoint_every)
         return result
 
     # ------------------------------------------------------------------
@@ -767,96 +771,111 @@ class FLEngine:
                     objective_state=payload.get("objective"))
         if st is None:
             st = backend.sweep_init(init_state, seeds, objectives=objs)
-        tr = backend.sweep_train(st, backend.sweep_batches(st), need_prio)
+        with spans.span("fl.round.batches", for_round=start):
+            batched = backend.sweep_batches(st)
+        with spans.span("fl.round.train", for_round=start):
+            tr = backend.sweep_train(st, batched, need_prio)
+        del batched
         for t in range(start, rounds):
-            last = t + 1 >= rounds
-            want_ckpt = (checkpoint_dir is not None
-                         and checkpoint_every > 0
-                         and (t + 1) % checkpoint_every == 0 and not last)
-            # the client-stream snapshot must precede ANY round-t+1
-            # batch draw (overlapped or not): a resumed run re-draws
-            # round t+1 from exactly this position
-            stream_snap = (backend.sweep_stream_states(st) if want_ckpt
-                           else None)
-            next_batched = None
-            if overlap and not last:
-                # host: round t+1's epoch permutations, drawn while the
-                # dispatched round-t train call runs on device
-                next_batched = backend.sweep_batches(st)
-            prios64 = np.asarray(tr.priorities, np.float64)  # (E, U) sync
-            winners_all, sels = self._select_lanes(
-                lanes, counters, prios64, t)
-            # channel gate + fault pipeline: merge weights are computed
-            # over the post-fault merge candidates (renormalized Eq. 1
-            # over survivors); counters and histories keep seeing the
-            # attempts. Stragglers' rows are captured BEFORE the merge
-            # donates the trained stack.
-            delivered_all, failures_all, rfs, stales = [], [], [], []
-            for e, lane in enumerate(lanes):
-                if lane.faults is not None:
-                    lane.faults.begin_round()
-                d, f = _gate_round(lane.channel, winners_all[e])
-                rf, stale_in = None, []
-                if lane.faults is not None:
-                    rf = lane.faults.process_uploads(
-                        winners_all[e], d,
-                        lane.channel.per if lane.channel is not None
-                        else None)
-                    d, f = rf.arrived, len(rf.failed)
-                    stale_in = lane.faults.pop_stale()
-                    for u in rf.stragglers:
-                        lane.faults.push_stale(
-                            u, backend.sweep_extract(tr, e, u),
-                            backend.num_examples(u))
-                delivered_all.append(d)
-                failures_all.append(f)
-                rfs.append(rf)
-                stales.append(stale_in)
-            merged_all = [[int(u) for u in
-                           (rf.merged_now if rf is not None else d)]
-                          for rf, d in zip(rfs, delivered_all)]
-            # dense sweep: user ids ARE the row indices into the
-            # (E, U, ...) trained stack (for attempts too)
-            k_pad = backend._k_pad(max(len(m) for m in merged_all))
-            nq = self._dispatch_sweep_merge(
-                lanes, st, tr, merged_all, merged_all, rfs, stales,
-                lead_faults, k_pad, t,
-                attempts=(winners_all, winners_all))
-            next_tr = None
-            if not last:
-                if next_batched is None:
-                    next_batched = backend.sweep_batches(st)
-                next_tr = backend.sweep_train(st, next_batched, need_prio)
-            # deferred bookkeeping: overlaps the in-flight train call
-            counters.update(winners_all)
-            losses64 = np.asarray(tr.losses, np.float64)
-            for e, lane in enumerate(lanes):
-                if rfs[e] is not None:
-                    lane.history.stale_merges += len(stales[e])
-                if nq is not None:
-                    lane.history.quarantined_updates += int(nq[e])
-                self._record_lane(lane, sels[e], winners_all[e],
-                                  delivered_all[e], failures_all[e],
-                                  losses64[e], prios64[e], rf=rfs[e])
-            if self.eval_fn is not None:
+            with spans.span("fl.round", round=t):
+                last = t + 1 >= rounds
+                want_ckpt = (checkpoint_dir is not None
+                             and checkpoint_every > 0
+                             and (t + 1) % checkpoint_every == 0 and not last)
+                # the client-stream snapshot must precede ANY round-t+1
+                # batch draw (overlapped or not): a resumed run re-draws
+                # round t+1 from exactly this position
+                stream_snap = (backend.sweep_stream_states(st) if want_ckpt
+                               else None)
+                next_batched = None
+                if overlap and not last:
+                    # host: round t+1's epoch permutations, drawn while the
+                    # dispatched round-t train call runs on device
+                    with spans.span("fl.round.batches", for_round=t + 1):
+                        next_batched = backend.sweep_batches(st)
+                with spans.span("fl.round.wait"):
+                    prios64 = np.asarray(tr.priorities, np.float64)  # sync
+                with spans.span("fl.round.select"):
+                    winners_all, sels = self._select_lanes(
+                        lanes, counters, prios64, t)
+                # channel gate + fault pipeline: merge weights are computed
+                # over the post-fault merge candidates (renormalized Eq. 1
+                # over survivors); counters and histories keep seeing the
+                # attempts. Stragglers' rows are captured BEFORE the merge
+                # donates the trained stack.
+                delivered_all, failures_all, rfs, stales = [], [], [], []
                 for e, lane in enumerate(lanes):
-                    spec = lane.spec
-                    if t % spec.eval_every == 0 or t == spec.rounds - 1:
-                        acc = float(self.eval_fn(
-                            backend.sweep_global(st, e)))
-                        lane.history.accuracy.append(acc)
-                        lane.history.eval_round.append(t)
-                        if verbose:
-                            tag = (labels[e] if labels
-                                   else f"{spec.strategy}/{e}")
-                            print(f"[{tag}] round {t:4d} acc {acc:.4f}"
-                                  f" loss {lane.history.train_loss[-1]:.4f}")
-            if want_ckpt:
-                save_fl_checkpoint(
-                    checkpoint_dir,
-                    self._sweep_payload(fp, t, st, stream_snap,
-                                        counters, lanes))
-            tr = next_tr
+                    if lane.faults is not None:
+                        lane.faults.begin_round()
+                    d, f = _gate_round(lane.channel, winners_all[e])
+                    rf, stale_in = None, []
+                    if lane.faults is not None:
+                        rf = lane.faults.process_uploads(
+                            winners_all[e], d,
+                            lane.channel.per if lane.channel is not None
+                            else None)
+                        d, f = rf.arrived, len(rf.failed)
+                        stale_in = lane.faults.pop_stale()
+                        for u in rf.stragglers:
+                            lane.faults.push_stale(
+                                u, backend.sweep_extract(tr, e, u),
+                                backend.num_examples(u))
+                    delivered_all.append(d)
+                    failures_all.append(f)
+                    rfs.append(rf)
+                    stales.append(stale_in)
+                merged_all = [[int(u) for u in
+                               (rf.merged_now if rf is not None else d)]
+                              for rf, d in zip(rfs, delivered_all)]
+                # dense sweep: user ids ARE the row indices into the
+                # (E, U, ...) trained stack (for attempts too)
+                k_pad = backend._k_pad(max(len(m) for m in merged_all))
+                with spans.span("fl.round.merge"):
+                    nq = self._dispatch_sweep_merge(
+                        lanes, st, tr, merged_all, merged_all, rfs, stales,
+                        lead_faults, k_pad, t,
+                        attempts=(winners_all, winners_all))
+                next_tr = None
+                if not last:
+                    if next_batched is None:
+                        with spans.span("fl.round.batches", for_round=t + 1):
+                            next_batched = backend.sweep_batches(st)
+                    with spans.span("fl.round.train", for_round=t + 1):
+                        next_tr = backend.sweep_train(st, next_batched,
+                                                      need_prio)
+                # deferred bookkeeping: overlaps the in-flight train call
+                with spans.span("fl.round.record"):
+                    counters.update(winners_all)
+                    losses64 = np.asarray(tr.losses, np.float64)
+                    for e, lane in enumerate(lanes):
+                        if rfs[e] is not None:
+                            lane.history.stale_merges += len(stales[e])
+                        if nq is not None:
+                            lane.history.quarantined_updates += int(nq[e])
+                        self._record_lane(lane, sels[e], winners_all[e],
+                                          delivered_all[e], failures_all[e],
+                                          losses64[e], prios64[e], rf=rfs[e])
+                if self.eval_fn is not None:
+                    for e, lane in enumerate(lanes):
+                        spec = lane.spec
+                        if t % spec.eval_every == 0 or t == spec.rounds - 1:
+                            with spans.span("fl.round.eval", lane=e):
+                                acc = float(self.eval_fn(
+                                    backend.sweep_global(st, e)))
+                            lane.history.accuracy.append(acc)
+                            lane.history.eval_round.append(t)
+                            if verbose:
+                                tag = (labels[e] if labels
+                                       else f"{spec.strategy}/{e}")
+                                loss = lane.history.train_loss[-1]
+                                print(f"[{tag}] round {t:4d} acc {acc:.4f}"
+                                      f" loss {loss:.4f}")
+                if want_ckpt:
+                    save_fl_checkpoint(
+                        checkpoint_dir,
+                        self._sweep_payload(fp, t, st, stream_snap,
+                                            counters, lanes))
+                tr = next_tr
         result = SweepResult(
             histories=[l.history for l in lanes],
             specs=[l.spec for l in lanes], labels=labels,
@@ -889,76 +908,85 @@ class FLEngine:
         st = backend.sweep_sparse_init(init_state, seeds,
                                        objectives=objs)
         for t in range(rounds):
-            prios, pre_losses = backend.sweep_sparse_priorities(
-                st, need_prio)
-            prios64 = np.asarray(prios, np.float64)
-            winners_all, sels = self._select_lanes(
-                lanes, counters, prios64, t)
-            tr = backend.sweep_sparse_train(st, winners_all)
-            delivered_all, failures_all, rfs, stales = [], [], [], []
-            for e, lane in enumerate(lanes):
-                if lane.faults is not None:
-                    lane.faults.begin_round()
-                d, f = _gate_round(lane.channel, winners_all[e])
-                rf, stale_in = None, []
-                if lane.faults is not None:
-                    rf = lane.faults.process_uploads(
-                        winners_all[e], d,
-                        lane.channel.per if lane.channel is not None
-                        else None)
-                    d, f = rf.arrived, len(rf.failed)
-                    stale_in = lane.faults.pop_stale()
-                    for u in rf.stragglers:
-                        lane.faults.push_stale(
-                            u, backend.sweep_extract(
-                                tr, e, winners_all[e].index(int(u))),
-                            backend.num_examples(u))
-                delivered_all.append(d)
-                failures_all.append(f)
-                rfs.append(rf)
-                stales.append(stale_in)
-            merged_all = [[int(u) for u in
-                           (rf.merged_now if rf is not None else d)]
-                          for rf, d in zip(rfs, delivered_all)]
-            # sparse sweep: row indices are compact DELIVERY positions
-            # into the (E, K_max, ...) winner stack; a lane's attempts
-            # ARE its trained rows, in order
-            pos_all = [[winners_all[e].index(u) for u in merged_all[e]]
-                       for e in range(E)]
-            att_pos = [list(range(len(ws))) for ws in winners_all]
-            k_pad = int(np.shape(tr.priorities)[1])       # = k_max
-            nq = self._dispatch_sweep_merge(
-                lanes, st, tr, merged_all, pos_all, rfs, stales,
-                lead_faults, k_pad, t,
-                attempts=(winners_all, att_pos))
-            counters.update(winners_all)
-            losses64 = (np.asarray(pre_losses, np.float64)
-                        if pre_losses is not None
-                        else np.asarray(tr.losses, np.float64))
-            for e, lane in enumerate(lanes):
-                if rfs[e] is not None:
-                    lane.history.stale_merges += len(stales[e])
-                if nq is not None:
-                    lane.history.quarantined_updates += int(nq[e])
-                # prepass rounds report full-cohort losses (the dense
-                # sweep's numbers); stale rounds report winner losses
-                loss_row = (losses64[e] if pre_losses is not None
-                            else losses64[e, :len(winners_all[e])])
-                self._record_lane(lane, sels[e], winners_all[e],
-                                  delivered_all[e], failures_all[e],
-                                  loss_row, prios64[e], rf=rfs[e])
-            if self.eval_fn is not None:
+            with spans.span("fl.round", round=t):
+                with spans.span("fl.round.prepass"):
+                    prios, pre_losses = backend.sweep_sparse_priorities(
+                        st, need_prio)
+                with spans.span("fl.round.wait"):
+                    prios64 = np.asarray(prios, np.float64)
+                with spans.span("fl.round.select"):
+                    winners_all, sels = self._select_lanes(
+                        lanes, counters, prios64, t)
+                with spans.span("fl.round.train", for_round=t):
+                    tr = backend.sweep_sparse_train(st, winners_all)
+                delivered_all, failures_all, rfs, stales = [], [], [], []
                 for e, lane in enumerate(lanes):
-                    spec = lane.spec
-                    if t % spec.eval_every == 0 or t == spec.rounds - 1:
-                        acc = float(self.eval_fn(
-                            backend.sweep_global(st, e)))
-                        lane.history.accuracy.append(acc)
-                        lane.history.eval_round.append(t)
-                        if verbose:
-                            tag = (labels[e] if labels
-                                   else f"{spec.strategy}/{e}")
-                            print(f"[{tag}] round {t:4d} acc {acc:.4f}")
+                    if lane.faults is not None:
+                        lane.faults.begin_round()
+                    d, f = _gate_round(lane.channel, winners_all[e])
+                    rf, stale_in = None, []
+                    if lane.faults is not None:
+                        rf = lane.faults.process_uploads(
+                            winners_all[e], d,
+                            lane.channel.per if lane.channel is not None
+                            else None)
+                        d, f = rf.arrived, len(rf.failed)
+                        stale_in = lane.faults.pop_stale()
+                        for u in rf.stragglers:
+                            lane.faults.push_stale(
+                                u, backend.sweep_extract(
+                                    tr, e, winners_all[e].index(int(u))),
+                                backend.num_examples(u))
+                    delivered_all.append(d)
+                    failures_all.append(f)
+                    rfs.append(rf)
+                    stales.append(stale_in)
+                merged_all = [[int(u) for u in
+                               (rf.merged_now if rf is not None else d)]
+                              for rf, d in zip(rfs, delivered_all)]
+                # sparse sweep: row indices are compact DELIVERY positions
+                # into the (E, K_max, ...) winner stack; a lane's attempts
+                # ARE its trained rows, in order
+                pos_all = [[winners_all[e].index(u) for u in merged_all[e]]
+                           for e in range(E)]
+                att_pos = [list(range(len(ws))) for ws in winners_all]
+                k_pad = int(np.shape(tr.priorities)[1])       # = k_max
+                with spans.span("fl.round.merge"):
+                    nq = self._dispatch_sweep_merge(
+                        lanes, st, tr, merged_all, pos_all, rfs, stales,
+                        lead_faults, k_pad, t,
+                        attempts=(winners_all, att_pos))
+                with spans.span("fl.round.record"):
+                    counters.update(winners_all)
+                    losses64 = (np.asarray(pre_losses, np.float64)
+                                if pre_losses is not None
+                                else np.asarray(tr.losses, np.float64))
+                    for e, lane in enumerate(lanes):
+                        if rfs[e] is not None:
+                            lane.history.stale_merges += len(stales[e])
+                        if nq is not None:
+                            lane.history.quarantined_updates += int(nq[e])
+                        # prepass rounds report full-cohort losses (the
+                        # dense sweep's numbers); stale rounds report
+                        # winner losses
+                        loss_row = (losses64[e] if pre_losses is not None
+                                    else losses64[e, :len(winners_all[e])])
+                        self._record_lane(lane, sels[e], winners_all[e],
+                                          delivered_all[e], failures_all[e],
+                                          loss_row, prios64[e], rf=rfs[e])
+                if self.eval_fn is not None:
+                    for e, lane in enumerate(lanes):
+                        spec = lane.spec
+                        if t % spec.eval_every == 0 or t == spec.rounds - 1:
+                            with spans.span("fl.round.eval", lane=e):
+                                acc = float(self.eval_fn(
+                                    backend.sweep_global(st, e)))
+                            lane.history.accuracy.append(acc)
+                            lane.history.eval_round.append(t)
+                            if verbose:
+                                tag = (labels[e] if labels
+                                       else f"{spec.strategy}/{e}")
+                                print(f"[{tag}] round {t:4d} acc {acc:.4f}")
         result = SweepResult(
             histories=[l.history for l in lanes],
             specs=[l.spec for l in lanes], labels=labels,

@@ -64,6 +64,7 @@ def aircomp_pallas(stacked, weights, noise, scale, *, interpret=False):
     grid = (cols // BLOCK_COLS,)
     out = pl.pallas_call(
         _kernel,
+        name="aircomp_combine",
         grid=grid,
         in_specs=[
             pl.BlockSpec((k, 1, BLOCK_COLS), lambda i: (0, 0, i)),

@@ -143,13 +143,14 @@ def contention_event_pallas(counters, live, doublings, windows, rand,
     row_out = jax.ShapeDtypeStruct((B, 1, npad), i32)
 
     step = pl.pallas_call(
-        _min_kernel, grid=grid,
+        _min_kernel, grid=grid, name="contention_min",
         in_specs=[row_blk, row_blk], out_specs=acc,
         out_shape=jax.ShapeDtypeStruct((B,), i32),
         interpret=interpret)(cnt, liv)
 
     nexp, winner = pl.pallas_call(
         functools.partial(_expiry_kernel, sentinel=npad), grid=grid,
+        name="contention_expiry",
         in_specs=[row_blk, row_blk, acc],
         out_specs=[acc, acc],
         out_shape=[jax.ShapeDtypeStruct((B,), i32),
@@ -159,6 +160,7 @@ def contention_event_pallas(counters, live, doublings, windows, rand,
     ncnt, ndbl, nact = pl.pallas_call(
         functools.partial(_transition_kernel,
                           max_doublings=max_doublings), grid=grid,
+        name="contention_transition",
         in_specs=[row_blk, row_blk, row_blk, row_blk, row_blk,
                   acc, acc],
         out_specs=[row_blk, row_blk, row_blk],

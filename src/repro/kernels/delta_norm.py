@@ -57,6 +57,7 @@ def delta_norm_pallas(w_local, w_global, *, interpret=False):
     grid = (rows // BLOCK_ROWS,)
     d2, g2 = pl.pallas_call(
         _kernel,
+        name="delta_norm",
         grid=grid,
         in_specs=[
             pl.BlockSpec((BLOCK_ROWS, LANES), lambda i: (i, 0)),

@@ -49,6 +49,7 @@ def fedavg_pallas(stacked, alphas, *, interpret=False):
     grid = (cols // BLOCK_COLS,)
     out = pl.pallas_call(
         _kernel,
+        name="fedavg_combine",
         grid=grid,
         in_specs=[
             pl.BlockSpec((k, 1, BLOCK_COLS), lambda i: (0, 0, i)),

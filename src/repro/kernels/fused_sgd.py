@@ -38,6 +38,7 @@ def fused_sgd_pallas(param, grad, lr, *, interpret=False):
     lr_arr = jnp.asarray(lr, jnp.float32).reshape(1, 1)
     out = pl.pallas_call(
         _kernel,
+        name="fused_sgd",
         grid=(rows // BLOCK_ROWS,),
         in_specs=[
             pl.BlockSpec((BLOCK_ROWS, LANES), lambda i: (i, 0)),

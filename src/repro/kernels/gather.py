@@ -87,6 +87,7 @@ def gather_combine_pallas(stacked, idx, weights, glob, *,
     )
     out = pl.pallas_call(
         _kernel,
+        name="gather_combine",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((1, cols), jnp.float32),
         interpret=interpret,

@@ -56,6 +56,7 @@ def robust_pallas(stacked, weights, scales, global_ref, *,
     grid = (cols // BLOCK_COLS,)
     out = pl.pallas_call(
         _kernel,
+        name="robust_combine",
         grid=grid,
         in_specs=[
             pl.BlockSpec((k, 1, BLOCK_COLS), lambda i: (0, 0, i)),

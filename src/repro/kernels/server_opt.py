@@ -62,6 +62,7 @@ def server_opt_pallas(avg, old, m, v, consts, *, interpret=False):
     row = pl.BlockSpec((1, BLOCK_COLS), lambda i: (0, i))
     out, nm, nv = pl.pallas_call(
         _kernel,
+        name="server_opt_combine",
         grid=grid,
         in_specs=[row, row, row, row,
                   pl.BlockSpec((1, 5), lambda i: (0, 0))],

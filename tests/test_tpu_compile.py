@@ -6,7 +6,8 @@ the chip's compiler, which refuses blocks that are not tiled to its
 (8, 128) layout and scalar stores to vector memory. These tests lower
 each ``*_pallas`` entry at the paper MLP's widths for one chip of a
 ``v5e:2x2`` topology and require the compiled program to hold the
-kernel (``tpu_custom_call``). The HostBackend round programs compile
+kernel (``tpu_custom_call``) under the name its Pallas call gives it,
+which a device trace shows. The HostBackend round programs compile
 too: with their kernels on one chip, and over the four chips of the
 topology with the cohort sharded, where GSPMD cannot partition a
 kernel and every ``kernels.ops`` call must take its jnp oracle.
@@ -18,6 +19,8 @@ library, and every test worker imports this file. The persistent
 compilation cache is off around these compiles, since an entry
 compiled for a described chip cannot be read back without one.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -74,6 +77,35 @@ CASES = {
     **{f"contention_event_{b}x{n}": _contention((b, n)) for b, n in POOLS},
 }
 
+#: the names the Pallas calls of each case give their custom calls
+KERNEL_NAMES = {
+    "fedavg": ("fedavg_combine",), "robust": ("robust_combine",),
+    "aircomp": ("aircomp_combine",), "server_opt": ("server_opt_combine",),
+    "fused_sgd": ("fused_sgd",), "delta_norm": ("delta_norm",),
+    "gather_combine": ("gather_combine",),
+    "contention_event": ("contention_min", "contention_expiry",
+                         "contention_transition"),
+}
+
+
+def _kernel_calls(compiled):
+    """The names each Mosaic custom call of the compiled program carries
+    in its ``op_name`` (the path segments, ``vmap(...)`` unwrapped)."""
+    out = []
+    for line in compiled.as_text().splitlines():
+        if 'custom_call_target="tpu_custom_call"' in line:
+            op = re.search(r'op_name="([^"]*)"', line).group(1)
+            out.append({re.sub(r"^(vmap\()+|\)+$", "", seg)
+                        for seg in op.split("/")})
+    return out
+
+
+def _names_ok(calls, names):
+    """Every kernel in ``names`` is there, and every custom call carries
+    one of the names."""
+    return (all(any(n in c for c in calls) for n in names)
+            and all(c & set(names) for c in calls))
+
 
 @pytest.fixture(scope="module")
 def topo():
@@ -111,7 +143,10 @@ def test_kernel_compiles_for_v5e(name, one_chip):
     args = [jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
             for shape, dtype in arg_specs]
     compiled = jax.jit(fn).lower(*args).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    calls = _kernel_calls(compiled)
+    assert calls
+    base = next(k for k in KERNEL_NAMES if name.startswith(k))
+    assert _names_ok(calls, KERNEL_NAMES[base])
 
 
 #: the cohort-sharded round programs: (round mode, program)
@@ -178,4 +213,6 @@ def test_unsharded_round_compiles_with_its_kernels(mode, which, one_chip,
         lambda s: (jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip)
                    if isinstance(s, jax.ShapeDtypeStruct) else s), args)
     compiled = fn.lower(*args).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    names = (("fused_sgd", "delta_norm") if which == "round"
+             else ("gather_combine",))
+    assert _names_ok(_kernel_calls(compiled), names)
