@@ -15,10 +15,13 @@ from repro.optim.sgd import sgd_update
 
 def sgd_epoch_scan(loss_fn: Callable, lr: float,
                    use_kernel: bool = True) -> Callable:
-    """Returns ``run(params, batched_data) -> (params, per_batch_losses)``:
-    one SGD step per batch, scanned. ``use_kernel=False`` keeps the
-    update on its jnp oracle (a Pallas call cannot be partitioned, so a
-    cohort sharded over several devices needs it).
+    """Returns ``run(params, batched_data, take=None) -> (params,
+    per_batch_losses)``: one SGD step per batch, scanned. With ``take``,
+    each step's slice of ``batched_data`` is what ``take`` makes its
+    batch from (the sweep round passes row indices and gathers them on
+    the device). ``use_kernel=False`` keeps the update on its jnp oracle
+    (a Pallas call cannot be partitioned, so a cohort sharded over
+    several devices needs it).
 
     THE local-SGD inner loop — the ragged per-user trainer, the stacked
     vmap path and the fused cohort round all build on this one scan,
@@ -26,8 +29,10 @@ def sgd_epoch_scan(loss_fn: Callable, lr: float,
     (their winner parity is pinned by ``tests/test_fused_round.py``).
     """
 
-    def run(params, batched_data):
+    def run(params, batched_data, take=None):
         def step(p, batch):
+            if take is not None:
+                batch = take(batch)
             loss, grads = jax.value_and_grad(loss_fn)(p, batch)
             return sgd_update(p, grads, lr, use_kernel), loss
 

@@ -66,6 +66,7 @@ DESIGN.md §3); backends never see the CSMA layer.
 """
 from __future__ import annotations
 
+import math
 from dataclasses import dataclass
 from typing import Any, Dict, List, Optional, Sequence
 
@@ -140,6 +141,58 @@ def compact_weights(k_pad: int, positions: Sequence[int],
         s = np.asarray(sizes, np.float64)
         w[:m] = (s / s.sum()).astype(np.float32)
     return idx, w
+
+
+#: one (8, 128) tile of the TPU's float32 vector registers. The resident
+#: data stack keeps each example as whole tiles of 128 lanes, so the
+#: device lays the stack out with the example index major and the round
+#: program's gather moves whole tiles. Left in their own shapes, stacks
+#: such as (U, n, 32, 32, 3) or (U, n, 784) are laid out with the
+#: example index minor, and the gather reads them lane by lane.
+TILE = (8, 128)
+
+
+def data_rows(leaf):
+    """A (U, n, ...) leaf of the data stack as the (U, n, R, 128) rows
+    the device keeps: each example flattened, zero-padded to whole
+    ``TILE``s and cut into rows of 128 lanes. A leaf of one value an
+    example stays (U, n)."""
+    if leaf.ndim == 2:
+        return leaf
+    flat = leaf.reshape(leaf.shape[:2] + (-1,))
+    pad = -flat.shape[2] % math.prod(TILE)
+    if pad:
+        flat = np.pad(flat, ((0, 0), (0, 0), (0, pad)))
+    return flat.reshape(flat.shape[:2] + (-1, TILE[1]))
+
+
+def take_rows(rows, idx, shapes):
+    """Examples ``idx`` of one user's ``rows`` (``data_rows`` leaves
+    without the U axis), one gather per leaf, each cut back to its
+    example shape in ``shapes``: a step's batch, gathered on the
+    device."""
+    def take(leaf, shape):
+        b = jnp.take(leaf, idx, axis=0, mode="clip")
+        if not shape:
+            return b
+        b = b.reshape(idx.shape + (-1,))[..., :math.prod(shape)]
+        return b.reshape(idx.shape + shape)
+    return jax.tree.map(take, rows, shapes)
+
+
+def train_lanes(run, stack, data, idx, shapes, lane=(), user=()):
+    """``run(params, idx, *lane, *user, take=...)`` (an epoch scan)
+    vmapped over the (E, U) cohort: ``lane`` arguments carry a leading
+    E axis, ``user`` arguments (E, U). ``idx`` is the (E, U, S, B) int32
+    index tensor of ``HostBackend.sweep_batches``, ``data`` the resident
+    stack (``data_rows``); each step gathers its batch (``take_rows``)."""
+    def per_user(params, xs, rows, *args):
+        return run(params, xs, *args,
+                   take=lambda i: take_rows(rows, i, shapes))
+    n, m = len(lane), len(user)
+    users = jax.vmap(per_user, in_axes=(0, 0, 0) + (None,) * n + (0,) * m)
+    return jax.vmap(users, in_axes=(0, 0, None) + (0,) * (n + m))(
+        stack, idx, data, *lane, *user)
 
 
 @dataclass
@@ -388,6 +441,8 @@ class HostBackend(Backend):
         self._resident = None      # device-resident merged cohort stack
         self._resident_key = None  # the global-state object it mirrors
         self._sweep_fns = {}       # E -> jitted sweep (bcast, round, merge)
+        self._sweep_data = {}      # placement -> resident data stack
+        #                            (data_rows)
         self._sweep_air_fns = {}   # E -> jitted AirComp sweep merge
         # robust-guard merge twins (fault layer), keyed by the static
         # program shape: (stale count, quarantine, clip_norm) and the
@@ -1319,6 +1374,7 @@ class HostBackend(Backend):
             # oracles
             uk = uk and self._mesh.size == 1
         epoch_run = sgd_epoch_scan(self._loss_fn, self._lr, uk)
+        shapes = jax.tree.map(lambda leaf: leaf.shape[2:], self._xstack)
 
         def bcast(g):
             glob = jax.tree.map(
@@ -1328,12 +1384,13 @@ class HostBackend(Backend):
                                            (E, U) + p.shape), g)
             return glob, stack
 
-        def sweep_round(stack, batched, need_prio):
+        def sweep_round(stack, data, idx, need_prio):
             # per-lane rows are identical at round start, so lane e's
             # Eq. 2 reference model is its row 0 — same trick as the
             # single-experiment fused step, one axis up
             glob = jax.tree.map(lambda p: p[:, 0], stack)
-            trained, losses = jax.vmap(jax.vmap(epoch_run))(stack, batched)
+            trained, losses = train_lanes(epoch_run, stack, data, idx,
+                                          shapes)
             loss_u = losses[:, :, -nb:].mean(axis=2)          # (E, U)
             if need_prio:
                 prios = jax.vmap(
@@ -1362,10 +1419,12 @@ class HostBackend(Backend):
         if shard:
             ss = sweep_sharding(self._mesh, E, U)
             gs = sweep_global_sharding(self._mesh, E)
+            # the data argument keeps the resident stack's placement
+            # (_data_sharding)
             fns = (
                 jax.jit(bcast, out_shardings=(gs, ss)),
-                jax.jit(sweep_round, static_argnums=2, donate_argnums=0,
-                        in_shardings=(ss, ss),
+                jax.jit(sweep_round, static_argnums=3, donate_argnums=0,
+                        in_shardings=(ss, None, ss),
                         out_shardings=(ss, ss, ss)),
                 jax.jit(sweep_merge, donate_argnums=(0, 3),
                         in_shardings=(ss, gs, gs, gs),
@@ -1374,7 +1433,7 @@ class HostBackend(Backend):
         else:
             fns = (
                 jax.jit(bcast),
-                jax.jit(sweep_round, static_argnums=2, donate_argnums=0),
+                jax.jit(sweep_round, static_argnums=3, donate_argnums=0),
                 jax.jit(sweep_merge, donate_argnums=(0, 3)),
             )
         self._sweep_fns[E] = fns
@@ -1393,6 +1452,7 @@ class HostBackend(Backend):
         self._ensure_xstack()
         nb, uk = self._nb, self._use_kernel
         obj_run = objective_epoch_scan(self._loss_fn, self._lr, use_h, uk)
+        shapes = jax.tree.map(lambda leaf: leaf.shape[2:], self._xstack)
 
         def _tail(trained, losses, glob, need_prio):
             loss_u = losses[:, :, -nb:].mean(axis=2)          # (E, U)
@@ -1405,24 +1465,20 @@ class HostBackend(Backend):
             return trained, loss_u, prios
 
         if use_h:
-            def sweep_obj_round(stack, batched, prox, h, need_prio):
+            def sweep_obj_round(stack, data, idx, prox, h, need_prio):
                 glob = jax.tree.map(lambda p: p[:, 0], stack)
-                trained, losses = jax.vmap(
-                    lambda s, b, g, p, hh: jax.vmap(
-                        obj_run, in_axes=(0, 0, None, None, 0))(
-                            s, b, g, p, hh))(stack, batched, glob,
-                                             prox, h)
+                trained, losses = train_lanes(
+                    obj_run, stack, data, idx, shapes, lane=(glob, prox),
+                    user=(h,))
+                return _tail(trained, losses, glob, need_prio)
+            static = 5
+        else:
+            def sweep_obj_round(stack, data, idx, prox, need_prio):
+                glob = jax.tree.map(lambda p: p[:, 0], stack)
+                trained, losses = train_lanes(
+                    obj_run, stack, data, idx, shapes, lane=(glob, prox))
                 return _tail(trained, losses, glob, need_prio)
             static = 4
-        else:
-            def sweep_obj_round(stack, batched, prox, need_prio):
-                glob = jax.tree.map(lambda p: p[:, 0], stack)
-                trained, losses = jax.vmap(
-                    lambda s, b, g, p: jax.vmap(
-                        obj_run, in_axes=(0, 0, None, None))(
-                            s, b, g, p))(stack, batched, glob, prox)
-                return _tail(trained, losses, glob, need_prio)
-            static = 3
         fn = jax.jit(sweep_obj_round, static_argnums=static,
                      donate_argnums=0)
         self._sweep_obj_round[(E, use_h)] = fn
@@ -1623,34 +1679,70 @@ class HostBackend(Backend):
                             st.rngs[e][u].permutation(n)[:take]
         return perms.transpose(0, 2, 1, 3).reshape(E, U, ep * take)
 
-    def sweep_batches(self, st: SweepState):
-        """(E, U, epochs*nb, bs, ...) round batches, one fancy-index
-        over the shared (U, n, ...) data stack (the data is read-only
-        and shared; only the index tensor is per-lane)."""
-        rows = np.arange(self.num_users)[None, :, None]
-        return self._gather_sweep_rows(rows, self._draw_sweep_big(st))
+    def _data_sharding(self, E: int):
+        """Placement of the resident data stack for an E-lane sweep:
+        where the sweep splits its users over the mesh the stack's U
+        axis splits the same way, where it splits its lanes every
+        device holds it all, and without such a mesh (None) it lives on
+        the default device."""
+        if self._mesh is None or not sweep_shardable(E, self.num_users,
+                                                     self._mesh):
+            return None
+        if sweep_sharding(self._mesh, E, self.num_users).spec[0]:
+            return replicated_sharding(self._mesh)
+        return cohort_sharding(self._mesh)
 
-    def sweep_train(self, st: SweepState, batched,
+    def _resident_data(self, E: int):
+        """The data stack on the device (``data_rows``), where the dense
+        E-lane sweep's round programs gather each round's batches. Put
+        there once per placement (``_data_sharding``) and kept for the
+        backend's life; the data is read-only, so no program donates
+        it."""
+        sharding = self._data_sharding(E)
+        key = None if sharding is None else sharding.spec
+        if key not in self._sweep_data:
+            self._ensure_xstack()
+            rows = jax.tree.map(data_rows, self._xstack)
+            self._sweep_data[key] = jax.device_put(rows, sharding)
+            spans.count("batches.resident_bytes", spans.host_nbytes(rows))
+        return self._sweep_data[key]
+
+    def sweep_batches(self, st: SweepState):
+        """One round's (E, U, epochs*nb, bs) int32 index tensor, from
+        each lane's and user's permutation draws: the round program
+        gathers the batches out of the resident data stack
+        (``_resident_data``) with it (the data is read-only and shared;
+        only the index tensor is per-lane)."""
+        big = self._draw_sweep_big(st)
+        spans.count("batches.device_gathers")
+        return big.astype(np.int32).reshape(
+            big.shape[:2] + (-1, self._batch_size))
+
+    def sweep_train(self, st: SweepState, idx,
                     need_priority: bool) -> SweepTrainResult:
-        """Dispatch ONE jitted train call for all E lanes; the incoming
-        stack is donated into the trained stack (residency chain)."""
+        """Dispatch ONE jitted train call for all E lanes on
+        ``sweep_batches``'s index tensor and the resident data stack;
+        the incoming stack is donated into the trained stack (residency
+        chain)."""
         if spans.on:
-            spans.count("batches.h2d_bytes", spans.host_nbytes(batched))
+            spans.count("batches.h2d_bytes", spans.host_nbytes(idx))
+        E = st.num_lanes
+        data = self._resident_data(E)
         stack, st.stack = st.stack, None      # donated below
         if st.obj is not None:
-            key = (st.num_lanes, st.obj.use_h)
+            key = (E, st.obj.use_h)
             rnd = (self._sweep_obj_round.get(key)
                    or self._build_sweep_obj_round(*key))
             prox = jnp.asarray(st.obj.prox)
             if st.obj.use_h:
-                trained, loss_u, prios = rnd(stack, batched, prox, st.h,
+                trained, loss_u, prios = rnd(stack, data, idx, prox, st.h,
                                              bool(need_priority))
             else:
-                trained, loss_u, prios = rnd(stack, batched, prox,
+                trained, loss_u, prios = rnd(stack, data, idx, prox,
                                              bool(need_priority))
         else:
-            _, rnd, _ = self._sweep_fns[st.num_lanes]
-            trained, loss_u, prios = rnd(stack, batched,
+            _, rnd, _ = self._sweep_fns[E]
+            trained, loss_u, prios = rnd(stack, data, idx,
                                          bool(need_priority))
         return SweepTrainResult(trained=trained, losses=loss_u,
                                 priorities=prios)
@@ -1969,6 +2061,7 @@ class HostBackend(Backend):
                             big_rows[e, j, k * take:(k + 1) * take] = \
                                 st.rngs[e][int(u)].permutation(n)[:take]
         batched = self._gather_sweep_rows(rows[:, :, None], big_rows)
+        spans.count("batches.host_gathers")
         if spans.on:
             spans.count("batches.h2d_bytes", spans.host_nbytes(batched))
         stack = st.stack if st.stack is not None else fns[0](st.glob)

@@ -26,13 +26,18 @@ at ``start``, so a span's length is monotonic.
 
 The engine opens ``fl.sweep`` around each ``run_sweep`` call (attribute
 ``job``), ``fl.round`` around each round (``round``) and, inside it,
-``fl.round.batches`` (``for_round``; children ``.draw`` and
-``.gather``), ``fl.round.prepass``, ``fl.round.train``,
-``fl.round.wait``, ``fl.round.select``, ``fl.round.merge``,
-``fl.round.record`` and ``fl.round.eval`` (``lane``). Counters:
-``batches.h2d_bytes`` (host batch arrays passed to the training
-programs), ``eval.h2d_bytes`` (test slices passed to the eval model) and
-``eval.fetches`` (logits copied back to the host by the eval).
+``fl.round.batches`` (``for_round``; children ``.draw`` and, where
+the host gathers the batches, ``.gather``), ``fl.round.prepass``,
+``fl.round.train``, ``fl.round.wait``, ``fl.round.select``,
+``fl.round.merge``, ``fl.round.record`` and ``fl.round.eval``
+(``lane``). Counters: ``batches.h2d_bytes`` (host arrays passed to the
+training programs: the batches, or the index tensor of a round gathered
+on the device), ``batches.device_gathers`` (dense sweep rounds, whose
+round program gathers its batches), ``batches.host_gathers``
+(winner-sparse rounds, gathered on the host), ``batches.resident_bytes``
+(the data stack, when it is put on the device), ``eval.h2d_bytes`` (test
+slices passed to the eval model) and ``eval.fetches`` (logits copied
+back to the host by the eval).
 
 The tracer never waits for the device and draws no random numbers: a
 run gives the same results with it on or off. One thread records at a

@@ -34,20 +34,22 @@ register_local(LocalObjective("feddyn", uses_h=True, coeff=lambda s: s.alpha))
 
 def objective_epoch_scan(loss_fn: Callable, lr: float, use_h: bool,
                          use_kernel: bool = True) -> Callable:
-    """Returns ``run(params, batched_data, glob, prox[, h]) ->
-    (params, per_batch_losses)``.
+    """Returns ``run(params, batched_data, glob, prox[, h], take=None)
+    -> (params, per_batch_losses)``.
 
     ``glob`` is the round-start global (the proximal anchor), ``prox``
     a scalar (traced, so one compiled program serves every coefficient —
     sweeps vmap a per-lane (E,) vector over it), ``h`` the per-user
     FedDyn state when ``use_h`` (structural: lanes without h-state in a
     mixed sweep ride the same program with an all-zero h row, which is
-    bitwise free — see module docstring). ``use_kernel`` as in
-    ``sgd_epoch_scan``.
+    bitwise free — see module docstring). ``take`` and ``use_kernel``
+    as in ``sgd_epoch_scan``.
     """
 
-    def run(params, batched_data, glob, prox, h=None):
+    def run(params, batched_data, glob, prox, h=None, take=None):
         def step(p, batch):
+            if take is not None:
+                batch = take(batch)
             loss, grads = jax.value_and_grad(loss_fn)(p, batch)
             grads = jax.tree.map(
                 lambda g, pp, wg: jnp.where(

@@ -10,8 +10,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.engine import (ExperimentSpec, SweepSpec, build_host_engine,
-                          make_accuracy_eval, spans)
+from repro.engine import (ExperimentSpec, SweepSpec, backends,
+                          build_host_engine, make_accuracy_eval, spans)
 
 # a paper-shaped cell at toy widths: U=10 non-IID users, K=2, eval
 # every round
@@ -114,8 +114,9 @@ def _batches_ok(rec, i, for_round, **attrs):
     assert name == "fl.round.batches"
     assert at == {"job": 0, "for_round": for_round, **attrs}
     kids = _children(rec, i)
-    assert [r[0] for _, r in kids] == ["fl.round.batches.draw",
-                                       "fl.round.batches.gather"]
+    # the fused round program gathers its batches on the device: the
+    # host only draws their rows
+    assert [r[0] for _, r in kids] == ["fl.round.batches.draw"]
     for _, (_, ka, kb, _, kat) in kids:
         assert a <= ka <= kb <= b and kat == at
 
@@ -202,11 +203,23 @@ def test_counters_match_the_shapes(cell, path):
     _, _, counters = _sweep(cell, path, traced=True)
     row = DIM * 4 + 4                  # float32 x, int32 y
     take = (N_PER_USER // BATCH) * BATCH
-    trained = NUM_USERS if path == "fused" else K
-    rows = ROUNDS * LANES * trained
-    if path == "sparse-prepass":       # the prepass trains every user
-        rows += ROUNDS * LANES * NUM_USERS
-    assert counters["batches.h2d_bytes"] == rows * take * row
+    if path == "fused":
+        # a round ships its int32 index tensor; the data stack goes to
+        # the device once
+        assert counters["batches.h2d_bytes"] == (
+            ROUNDS * LANES * NUM_USERS * take * 4)
+        tile = math.prod(backends.TILE)  # x padded to whole tiles
+        assert counters["batches.resident_bytes"] == (
+            NUM_USERS * N_PER_USER * (-(-DIM // tile) * tile * 4 + 4))
+        assert counters["batches.device_gathers"] == ROUNDS
+        assert "batches.host_gathers" not in counters
+    else:
+        rows = ROUNDS * LANES * K
+        if path == "sparse-prepass":   # the prepass trains every user
+            rows += ROUNDS * LANES * NUM_USERS
+        assert counters["batches.h2d_bytes"] == rows * take * row
+        assert counters["batches.host_gathers"] == ROUNDS
+        assert "batches.device_gathers" not in counters
     evals = ROUNDS * LANES
     assert counters["eval.h2d_bytes"] == evals * N_TEST * DIM * 4
     assert counters["eval.fetches"] == evals * math.ceil(N_TEST / EVAL_BATCH)

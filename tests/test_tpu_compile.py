@@ -10,7 +10,9 @@ kernel (``tpu_custom_call``) under the name its Pallas call gives it,
 which a device trace shows. The HostBackend round programs compile
 too: with their kernels on one chip, and over the four chips of the
 topology with the cohort sharded, where GSPMD cannot partition a
-kernel and every ``kernels.ops`` call must take its jnp oracle.
+kernel and every ``kernels.ops`` call must take its jnp oracle. The
+dense sweep's round program compiles at the paper cells' shapes with
+the data stack as its argument.
 Nothing runs.
 
 The topology is described inside a module-scoped fixture, never while
@@ -160,6 +162,7 @@ def _round_program(mode, which, mesh):
     argument shapes; the cohort (fused: U users, one sweep lane) or the
     winner stack (sparse: K rows) splits over ``mesh`` when given."""
     from repro.engine import build_host_engine
+    from repro.engine.backends import data_rows
     from repro.launch.train import build_parser, paper_cell
 
     args = build_parser().parse_args(
@@ -179,8 +182,13 @@ def _round_program(mode, which, mesh):
                       x.dtype), be._xstack)
     idx, w = sds(lead + (SHARD_K,), I32), sds(lead + (SHARD_K,), F32)
     if mode == "fused":
+        # the round gathers its batches out of the resident data stack
         _, rnd, merge = be._build_sweep_fns(1)
-        return {"round": (rnd, (stack, batched, True)),
+        data = jax.tree.map(
+            lambda x: sds(x.shape, x.dtype, sharding=be._data_sharding(1)),
+            jax.tree.map(data_rows, be._xstack))
+        rows_idx = sds(rows + (be._nb, be._batch_size), I32)
+        return {"round": (rnd, (stack, data, rows_idx, True)),
                 "merge": (merge, (stack, idx, w, glob))}[which]
     be._build_sparse()
     return {"round": (be._sparse_round, (stack, batched)),
@@ -203,6 +211,26 @@ def test_sharded_round_compiles_for_four_v5e_chips(mode, which, topo,
     assert "tpu_custom_call" not in compiled.as_text()
 
 
+def test_sharded_round_gathers_from_its_own_shard(topo, no_compile_cache,
+                                                  monkeypatch):
+    """With the cohort split over four chips, each chip's steps gather
+    their batches out of its own quarter of the resident data stack:
+    the program moves no part of the stack between chips."""
+    from repro.kernels import ops as kops
+    from repro.sharding.cohort import cohort_mesh
+    monkeypatch.setattr(kops, "_mode", lambda uk, interp: (bool(uk), False))
+    fn, (stack, data, idx, prio) = _round_program(
+        "fused", "round", cohort_mesh(topo.devices))
+    text = fn.lower(stack, data, idx, prio).compile().as_text()
+    assert not re.search(r"\b(all-gather|all-to-all|collective-permute)"
+                         r"(-start)?\(", text)
+    x = data["x"].shape
+    shard = ",".join(map(str, (x[0] // len(topo.devices),) + x[1:]))
+    operands = re.findall(r"= \S+ gather\(%(\S+),", text)
+    params = dict(re.findall(r"%(\S+) = (\w+\[[\d,]*\])", text))
+    assert f"f32[{shard}]" in {params.get(p) for p in operands}
+
+
 @pytest.mark.parametrize("mode,which", ROUND_PROGRAMS)
 def test_unsharded_round_compiles_with_its_kernels(mode, which, one_chip,
                                                    monkeypatch):
@@ -216,3 +244,60 @@ def test_unsharded_round_compiles_with_its_kernels(mode, which, one_chip,
     names = (("fused_sgd", "delta_norm") if which == "round"
              else ("gather_combine",))
     assert _names_ok(_kernel_calls(compiled), names)
+
+
+#: the benchmark's paper cells: (model, dataset, examples a user, shape
+#: of an example), U=10 users, batch 32, one sweep lane
+PAPER_CELLS = {"mlp-fashion": ("mlp", "fashion", 6000, (784,)),
+               "cnn-cifar": ("cnn", "cifar", 5000, (32, 32, 3))}
+
+
+@pytest.mark.parametrize("name", sorted(PAPER_CELLS))
+def test_sweep_round_gathers_from_the_resident_stack(name, one_chip,
+                                                     monkeypatch):
+    """The dense sweep's round program at a paper cell's shapes takes
+    the data stack, one row an example, as an argument, not a constant,
+    and gathers the round's batches out of it."""
+    import math
+
+    import numpy as np
+    from repro.engine import HostBackend
+    from repro.engine.backends import TILE
+    from repro.kernels import ops as kops
+    from repro.models.paper_models import get_paper_model
+    monkeypatch.setattr(kops, "_mode", lambda uk, interp: (bool(uk), False))
+    model, dataset, n, shape = PAPER_CELLS[name]
+    init, apply_fn = get_paper_model(model, dataset)
+
+    def loss_fn(params, batch):
+        logits = apply_fn(params, batch["x"])
+        oh = jax.nn.one_hot(batch["y"], 10)
+        return -jnp.mean(jnp.sum(oh * jax.nn.log_softmax(logits), -1))
+    bs = 32
+    # views of one zero: the shapes of the cell's data, not its bytes
+    user = {"x": np.broadcast_to(np.float32(0), (n,) + shape),
+            "y": np.broadcast_to(np.int32(0), (n,))}
+    be = HostBackend(loss_fn, [user] * U, batch_size=bs)
+    sds = jax.ShapeDtypeStruct
+    be._xstack = {"x": sds((U, n) + shape, F32), "y": sds((U, n), I32)}
+    be._nb = n // bs
+    _, rnd, _ = be._build_sweep_fns(1)
+    params = jax.eval_shape(init, jax.random.key(0))
+    stack = jax.tree.map(
+        lambda p: sds((1, U) + p.shape, p.dtype, sharding=one_chip), params)
+    tile = math.prod(TILE)
+    rows = -(-math.prod(shape) // tile) * TILE[0]
+    data = {"x": sds((U, n, rows, TILE[1]), F32, sharding=one_chip),
+            "y": sds((U, n), I32, sharding=one_chip)}
+    idx = sds((1, U, be._nb, bs), I32, sharding=one_chip)
+    compiled = rnd.lower(stack, data, idx, True).compile()
+    text = compiled.as_text()
+    # the examples keep their index major on the chip
+    dims = f"{U},{n},{rows},{TILE[1]}"
+    assert re.search(rf"= f32\[{dims}\]{{3,2,1,0:\S* parameter\(", text)
+    assert re.search(rf"= s32\[{U},{n}\]\S* parameter\(", text)
+    assert not re.search(rf"f32\[{dims}\]\S* constant\(", text)
+    assert re.search(r"\bgather\(", text)
+    stack_bytes = U * n * (4 * rows * TILE[1] + 4)
+    assert compiled.memory_analysis().argument_size_in_bytes >= stack_bytes
+    assert _names_ok(_kernel_calls(compiled), ("fused_sgd", "delta_norm"))
