@@ -12,17 +12,24 @@ file):
 - ``prio_gap``: worst gap of a user's Eq. 2 priority, taken on
   ``priority - 1`` against the larger of the reference's value and its
   median over the users that moved.
-- ``acc_gap``: worst absolute gap of the merged model's accuracy.
-- ``update_gap``: the first round's Eq. 1 update ``g1 - g0``, by the
-  worst leaf: the gap between the two norms of that leaf, against the
-  larger of the reference's norm of the leaf and of the median leaf.
+- ``acc_gap``: worst absolute gap of the merged model's eval value:
+  accuracy for a ``classify`` configuration, held-out mean next-token
+  loss for a ``next_token`` one.
+- ``update_gap``: the first round's Eq. 1 update ``g1 - g0`` of the
+  trained part, by the worst leaf: the gap between the two norms of
+  that leaf, against the larger of the reference's norm of the leaf and
+  of the median leaf. Globals whose leaves differ from the reference's
+  (a frozen part trained, a leaf left out) cannot be compared: inf.
 - ``change_gap``: the same for the change ``gT - g0`` over the rounds
   compared.
 
 Where a winner list differs only because a backoff lies on a slot
 boundary (the reference's selection, fed the program's priorities,
-gives the program's winners), the rounds after it start from other
-models and are not compared; the numbers of the rounds before stand.
+gives the program's winners), the reference, run with the program's
+trajectory as its probe, selects as the program did and goes on
+(``reference.run_job``). A pair of trajectories that still differs
+there stops at that round: the rounds after start from other models and
+are not compared; the numbers of the rounds before stand.
 """
 from __future__ import annotations
 
@@ -44,8 +51,16 @@ def _leaves(tree):
     return [np.asarray(x, np.float64) for x in jax.tree.leaves(tree)]
 
 
+def _shapes(tree):
+    return (jax.tree.structure(tree),
+            [np.shape(x) for x in jax.tree.leaves(tree)])
+
+
 def norm_gap(prog_a, prog_b, ref_a, ref_b) -> float:
-    """Worst leaf gap of ``|b - a|`` between program and reference."""
+    """Worst leaf gap of ``|b - a|`` between program and reference; inf
+    where the program's leaves are not the reference's."""
+    if not (_shapes(prog_a) == _shapes(prog_b) == _shapes(ref_a)):
+        return math.inf
     pn = [np.linalg.norm(b - a) for a, b in zip(_leaves(prog_a),
                                                 _leaves(prog_b))]
     rn = [np.linalg.norm(b - a) for a, b in zip(_leaves(ref_a),
@@ -65,9 +80,10 @@ def _prio_gap(p, r) -> float:
 
 
 def compare(prog, ref) -> dict:
-    """``{"checks": {name: value}, "rounds_compared": n}``; inf marks a
-    number that could not be read (a missing round), NaN one that the
-    program made NaN."""
+    """``{"checks": {name: value}, "rounds_compared": n,
+    "rounds_followed": m}``, ``m`` the rounds in which the reference
+    selected on the program's priorities; inf marks a number that could
+    not be read (a missing round), NaN one that the program made NaN."""
     rounds = len(ref.winners)
     faults, stop = 0, rounds
     for t in range(rounds):
@@ -100,7 +116,8 @@ def compare(prog, ref) -> dict:
         update_gap = change_gap = 0.0
     values = (faults, loss_gap, prio_gap, acc_gap, update_gap, change_gap)
     checks = {k: float(v) for k, v in zip(CHECKS, values)}
-    return {"checks": checks, "rounds_compared": post}
+    return {"checks": checks, "rounds_compared": post,
+            "rounds_followed": ref.followed}
 
 
 def judge(checks: dict, limits: dict) -> bool:

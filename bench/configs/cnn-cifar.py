@@ -44,9 +44,10 @@ def apply(params, x):
     return jnp.dot(x, params["fc"]["w"]) + params["fc"]["b"]
 
 
-def train_flops_per_example(cfg):
+def train_flops_per_example(cfg, tr):
     """Multiply-adds x2 of one SGD example: forward, weight gradients,
-    and input gradients of every layer but the first."""
+    and input gradients of every layer but the first (``tr``, the
+    traffic, sets no size of an image)."""
     k = cfg["kernel"]
     hw = cfg["input_shape"][0]
     cin = cfg["input_shape"][2]

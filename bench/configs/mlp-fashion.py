@@ -20,10 +20,10 @@ def apply(params, x):
     return jnp.dot(h, params["fc2"]["w"]) + params["fc2"]["b"]
 
 
-def train_flops_per_example(cfg):
+def train_flops_per_example(cfg, tr):
     """Multiply-adds x2 of one SGD example: the forward pass, the weight
     gradients, and the input gradient of every layer but the first (the
-    data needs none)."""
+    data needs none; ``tr``, the traffic, sets no size of an image)."""
     d, h, c = cfg["d_input"], cfg["d_hidden"], cfg["n_classes"]
     fwd1, fwd2 = 2 * d * h, 2 * h * c
     return fwd1 + fwd2 + fwd1 + 2 * fwd2

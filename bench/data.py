@@ -1,12 +1,20 @@
 """The benchmark's own synthetic data: a copy of the class-template
 generator the program ships (`repro.data.synthetic`) and of its two
-partitions (`repro.data.partition`), so that a change to the program's
-data code cannot move the yardstick.
+partitions (`repro.data.partition`), and a token generator with the
+semantics of its ``make_token_stream``, so that a change to the
+program's data code cannot move the yardstick.
 
-Images are class templates (smooth random cosines) plus noise and
-per-sample distortions, in [0, 1], NHWC float32, at the published
-shapes of the stand-in dataset. Everything is drawn from the seed; no
-file is read.
+A configuration's ``task`` picks the generator (``make_task_data``):
+
+- ``classify``: images are class templates (smooth random cosines) plus
+  noise and per-sample distortions, in [0, 1], NHWC float32, at the
+  published shapes of the stand-in dataset; an example is an image and
+  its label, ``{"x", "y"}``;
+- ``next_token``: an example is one int32 sequence of ``seq_len + 1``
+  token ids, ``{"tokens"}``, drawn from the Zipf(1.1) law of one topic
+  over that topic's own permutation of the vocabulary.
+
+Everything is drawn from the seed; no file is read.
 """
 from __future__ import annotations
 
@@ -94,3 +102,64 @@ def partition(x, y, num_users: int, kind: str, seed: int,
     assign = rng.permutation(n_shards).reshape(num_users, shards_per_user)
     return [np.concatenate([order[s * shard:(s + 1) * shard] for s in row])
             for row in assign]
+
+
+#: exponent of the Zipf law over a topic's token ranks
+ZIPF_EXPONENT = 1.1
+
+
+def make_token_data(seed: int, vocab_size: int, num_users: int,
+                    per_user: int, seq_len: int, n_test: int,
+                    kind: str, topics: int = 8):
+    """Per-user ``{"tokens": (per_user, seq_len + 1)}`` int32 arrays and
+    the test split ``{"tokens": (n_test, seq_len + 1)}``, drawn from
+    ``seed``. Each topic is a Zipf(1.1) law over its own permutation of
+    the ``vocab_size`` ids; each sequence is drawn from one topic.
+    ``noniid`` gives a user 2 topics (the paper's 2-shard label skew),
+    ``iid`` all of them; the test split draws from all topics."""
+    if kind not in ("iid", "noniid"):
+        raise ValueError(f"unknown partition {kind!r}")
+    perm_ss, user_ss, test_ss = np.random.SeedSequence(int(seed)).spawn(3)
+    prng = np.random.default_rng(perm_ss)
+    perms = np.stack([prng.permutation(vocab_size) for _ in range(topics)])
+    cdf = np.cumsum(np.arange(1, vocab_size + 1, dtype=np.float64)
+                    ** -ZIPF_EXPONENT)
+    cdf /= cdf[-1]
+
+    def draw(rng, n, mine):
+        t = mine[rng.integers(0, len(mine), size=n)]
+        ranks = np.searchsorted(cdf, rng.random((n, seq_len + 1)),
+                                side="right")
+        ranks = np.minimum(ranks, vocab_size - 1)
+        return {"tokens": perms[t[:, None], ranks].astype(np.int32)}
+
+    every = np.arange(topics)
+    users = []
+    for ss in user_ss.spawn(num_users):
+        rng = np.random.default_rng(ss)
+        mine = (rng.choice(topics, size=2, replace=False)
+                if kind == "noniid" else every)
+        users.append(draw(rng, per_user, mine))
+    return users, draw(np.random.default_rng(test_ss), n_test, every)
+
+
+def make_task_data(seed: int, cfg: dict, tr: dict):
+    """``(users, test)`` of a cell: per-user dicts of arrays whose first
+    axis counts the user's examples, and the test split, drawn from
+    ``seed`` by the generator of the configuration's ``task``."""
+    task = cfg.get("task", "classify")
+    U, epu = tr["users"], tr["examples_per_user"]
+    if task == "next_token":
+        return make_token_data(seed, cfg["vocab_size"], U, epu,
+                               tr["seq_len"], cfg["n_test"],
+                               tr["partition"], tr.get("topics", 8))
+    if task != "classify":
+        raise ValueError(f"unknown task {task!r}")
+    (x, y), (xt, yt) = make_dataset(seed, cfg["input_shape"],
+                                    cfg["n_classes"], U * epu, cfg["n_test"])
+    if cfg["input_layout"] == "flat":
+        x = x.reshape(len(x), -1)
+        xt = xt.reshape(len(xt), -1)
+    parts = partition(x, y, U, tr["partition"], seed,
+                      tr.get("shards_per_user", 2))
+    return [{"x": x[i], "y": y[i]} for i in parts], {"x": xt, "y": yt}

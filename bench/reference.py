@@ -27,6 +27,20 @@ in the candidate pool of the smallest counters.
 Model arithmetic is float32 under ``jax.default_matmul_precision
 ("highest")``; ``dtype=bfloat16`` gives the control, the same job in the
 nearest lower precision.
+
+A configuration's reference module gives ``init`` and may give three
+hooks; where one is absent, a classifier whose weights are all trained
+is assumed:
+
+- ``split(weights) -> (trained, frozen)``: SGD, Eq. 2 and Eq. 1 run
+  over the trained part alone; the frozen part is shared by every user
+  and never moves (default: all trained);
+- ``loss(trained, frozen, batch, cfg)``: a batch's mean training loss
+  (default: cross-entropy of ``apply(trained, batch["x"])`` against
+  ``batch["y"]``);
+- ``evaluate(trained, frozen, test, cfg, dtype)``: the value the eval
+  reports each round (default: accuracy on ``test["x"]``,
+  ``test["y"]``).
 """
 from __future__ import annotations
 
@@ -211,27 +225,60 @@ class Selector:
 
 
 # ------------------------------------------------------ local training
-def _loss(apply, params, x, y):
-    logp = jax.nn.log_softmax(apply(params, x))
-    return -jnp.mean(jnp.take_along_axis(logp, y[:, None], axis=1))
+def _cast(tree, dtype):
+    """Floating leaves of ``tree`` in ``dtype``; integer leaves (labels,
+    token ids) as they are."""
+    return jax.tree.map(
+        lambda a: a.astype(dtype) if jnp.issubdtype(a.dtype, jnp.floating)
+        else a, tree)
 
 
-def make_epoch(apply, lr, dtype):
-    """``epoch(params, xb, yb) -> (params, mean batch loss)``: one SGD
-    step per batch of ``xb`` (nb, bs, ...), in ``dtype``."""
+def split(model, weights):
+    """``(trained, frozen)`` by the configuration's ``split`` hook; all
+    trained without one."""
+    if hasattr(model, "split"):
+        return model.split(weights)
+    return weights, {}
+
+
+def loss_of(model):
+    """The configuration's ``loss`` hook, else cross-entropy of its
+    ``apply`` over class logits."""
+    if hasattr(model, "loss"):
+        return model.loss
+
+    def xent(trained, frozen, batch, cfg):
+        logp = jax.nn.log_softmax(model.apply(trained, batch["x"]))
+        return -jnp.mean(jnp.take_along_axis(logp, batch["y"][:, None],
+                                             axis=1))
+    return xent
+
+
+def evaluate(model, trained, frozen, test, cfg, dtype):
+    """The configuration's ``evaluate`` hook, else arg-max accuracy."""
+    if hasattr(model, "evaluate"):
+        return float(model.evaluate(trained, frozen, test, cfg, dtype))
+    return accuracy(model.apply, trained, test["x"], test["y"], dtype)
+
+
+def make_epoch(loss, cfg, lr, dtype):
+    """``epoch(trained, frozen, batches) -> (trained, mean batch loss)``:
+    one SGD step of the trained part per batch of ``batches`` (leaves
+    (nb, bs, ...)), in ``dtype``."""
 
     @jax.jit
-    def epoch(params, xb, yb):
-        params = jax.tree.map(lambda p: p.astype(dtype), params)
+    def epoch(params, frozen, batches):
+        params = _cast(params, dtype)
+        frozen = _cast(frozen, dtype)
 
         def step(p, batch):
-            x, y = batch
-            loss, g = jax.value_and_grad(
-                lambda q: _loss(apply, q, x.astype(dtype), y))(p)
+            batch = _cast(batch, dtype)
+            value, g = jax.value_and_grad(
+                lambda q: loss(q, frozen, batch, cfg))(p)
             return jax.tree.map(lambda a, b: a - jnp.asarray(lr, dtype) * b,
-                                p, g), loss.astype(jnp.float32)
+                                p, g), value.astype(jnp.float32)
 
-        params, losses = jax.lax.scan(step, params, (xb, yb))
+        params, losses = jax.lax.scan(step, params, batches)
         return (jax.tree.map(lambda p: p.astype(jnp.float32), params),
                 losses.mean())
 
@@ -275,8 +322,9 @@ def accuracy(apply, params, x, y, dtype, batch=500):
 class Trajectory:
     """What one job produced in its first rounds: per round the winners
     in delivery order, the mean training loss, the priorities selection
-    saw, the accuracy of the merged model, and the globals before round
-    0 and after each round (host arrays)."""
+    saw, the eval value of the merged model (accuracy, or a held-out
+    loss), and the trained part of the globals before round 0 and after
+    each round (host arrays)."""
     winners: List[List[int]] = field(default_factory=list)
     losses: List[float] = field(default_factory=list)
     priorities: List[np.ndarray] = field(default_factory=list)
@@ -285,53 +333,78 @@ class Trajectory:
     #: winners the reference's selection gives for the priorities of
     #: another trajectory, round by round (see ``run_job``'s probe)
     probe_winners: List[List[int]] = field(default_factory=list)
+    #: rounds in which the job selected on the probe's priorities
+    followed: int = 0
 
 
-def _batches(rng, x, y, nb, bs):
-    perm = rng.permutation(len(y))[:nb * bs]
-    return (x[perm].reshape((nb, bs) + x.shape[1:]),
-            y[perm].reshape(nb, bs))
+def examples(data) -> int:
+    """A user's example count: the first leaf's length (Eq. 1's
+    weight)."""
+    return len(jax.tree.leaves(data)[0])
 
 
-def run_job(apply, init_params, users, test, wl, seed, rounds, *,
+def _batches(rng, data, nb, bs):
+    perm = rng.permutation(examples(data))[:nb * bs]
+    return jax.tree.map(
+        lambda a: a[perm].reshape((nb, bs) + a.shape[1:]), data)
+
+
+def run_job(model, cfg, weights, users, test, wl, seed, rounds, *,
             dtype=jnp.float32, probe: Optional[Trajectory] = None,
-            fault: Optional[str] = None) -> Trajectory:
-    """The first ``rounds`` rounds of one job from ``init_params``.
+            fault: Optional[str] = None, shards: int = 1) -> Trajectory:
+    """The first ``rounds`` rounds of one job from the initial
+    ``weights`` of the configuration ``cfg``, whose reference module is
+    ``model`` (its hooks: see the module's docstring).
 
-    ``users``: per-user ``(x, y)`` host arrays; ``test``: ``(x, y)``.
+    ``users``: per-user dicts of host arrays whose first axis counts the
+    examples; ``test``: the test split.
     ``probe``: another trajectory whose round-t priorities are also put
     through this job's round-t selection state (without advancing it),
     so that a different winner list can be told apart from a selection
-    that gives those priorities another outcome.
+    that gives those priorities another outcome. Where that selection
+    gives the probe's own winners and this job's priorities give others
+    (a backoff on a slot boundary), the round selects on the probe's
+    priorities: the job goes on with the probe's winners, merged from
+    its own models, and the rounds after stay comparable (counted in
+    ``followed``). Where it does not, the job keeps its own winners and
+    the comparison counts a selection fault.
 
     ``fault`` plants one fault, for the readings the limits are set
     from: ``"unchanged"`` keeps the global model as it was (a merge that
     returns its state), ``"half"`` trains on the first half of every
-    batch (the mean taken over the rest)."""
+    batch (the mean taken over the rest), ``"exchange"`` leaves out the
+    exchange between the ``shards`` chips that split the users: Eq. 1
+    over the winners of the first chip's share, users ``[0, U /
+    shards)``, its weights renormalised (the global is kept where that
+    share has no winner)."""
+    if fault == "exchange" and shards < 2:
+        raise ValueError("the exchange fault needs users split over "
+                         "two or more chips")
     with jax.default_matmul_precision("highest"):
-        return _run_job(apply, init_params, users, test, wl, seed, rounds,
-                        dtype, probe, fault)
+        return _run_job(model, cfg, weights, users, test, wl, seed, rounds,
+                        dtype, probe, fault, shards)
 
 
-def _run_job(apply, init_params, users, test, wl, seed, rounds, dtype,
-             probe, fault):
+def _run_job(model, cfg, weights, users, test, wl, seed, rounds, dtype,
+             probe, fault, shards):
     U = len(users)
     bs = int(wl["batch_size"])
-    n = len(users[0][1])
-    nb = max(1, n // bs)
+    nb = max(1, examples(users[0]) // bs)
     stale = wl["round_mode"] == "sparse"
-    epoch = make_epoch(apply, float(wl["lr"]), dtype)
+    epoch = make_epoch(loss_of(model), cfg, float(wl["lr"]), dtype)
     sel = Selector(seed, U, wl)
     rngs = [client_rng(seed, u) for u in range(U)]
-    glob = jax.tree.map(lambda a: jnp.asarray(a, jnp.float32), init_params)
+    part, frozen = split(model, weights)
+    as32 = lambda t: jax.tree.map(lambda a: jnp.asarray(a, jnp.float32), t)
+    glob, frozen = as32(part), as32(frozen)
     cache = np.ones(U, np.float64)
     traj = Trajectory(globals=[jax.device_get(glob)])
 
     def train(u):
-        xb, yb = _batches(rngs[u], *users[u], nb, bs)
+        batches = _batches(rngs[u], users[u], nb, bs)
         if fault == "half":
-            xb, yb = xb[:, :bs // 2], yb[:, :bs // 2]
-        local, loss = epoch(glob, xb, yb)
+            batches = jax.tree.map(lambda a: a[:, :bs // 2], batches)
+        local, loss = epoch(glob, frozen, batches)
         return local, float(loss), float(priority(local, glob))
 
     for t in range(rounds):
@@ -340,9 +413,19 @@ def _run_job(apply, init_params, users, test, wl, seed, rounds, dtype,
         else:
             trained = [train(u) for u in range(U)]
             prios = np.array([p for _, _, p in trained], np.float64)
+        chosen = prios
         if probe is not None and t < len(probe.priorities):
-            traj.probe_winners.append(sel.peek(probe.priorities[t]))
-        winners = sel.select(prios)
+            theirs = sel.peek(probe.priorities[t])
+            traj.probe_winners.append(theirs)
+            if (theirs == list(probe.winners[t])
+                    and sel.peek(prios) != theirs):
+                # a backoff on a slot boundary: this selection, fed the
+                # probe's priorities, gives the probe's winners; select
+                # as the probe did, so that the rounds after merge the
+                # same users' models
+                chosen = probe.priorities[t]
+                traj.followed += 1
+        winners = sel.select(chosen)
         if stale:
             trained = [train(u) for u in winners]
             for u, (_, _, p) in zip(winners, trained):
@@ -351,12 +434,15 @@ def _run_job(apply, init_params, users, test, wl, seed, rounds, dtype,
         else:
             locals_ = [trained[u][0] for u in winners]
         losses = [l for _, l, _ in trained]
-        if winners and fault != "unchanged":
-            glob = merge(locals_, [len(users[u][1]) for u in winners])
+        merged = [(u, m) for u, m in zip(winners, locals_)
+                  if fault != "exchange" or u < U // shards]
+        if merged and fault != "unchanged":
+            glob = merge([m for _, m in merged],
+                         [examples(users[u]) for u, _ in merged])
         traj.winners.append(list(winners))
         traj.losses.append(float(np.mean(np.asarray(losses, np.float64)))
                            if losses else float("nan"))
         traj.priorities.append(prios)
-        traj.accuracy.append(accuracy(apply, glob, *test, dtype))
+        traj.accuracy.append(evaluate(model, glob, frozen, test, cfg, dtype))
         traj.globals.append(jax.device_get(glob))
     return traj

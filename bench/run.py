@@ -5,12 +5,17 @@
 A cell (``bench/workloads/<cell>.json``) names a model configuration
 (``bench/configs/<config>.json`` and its plain reference beside it) and
 a traffic mix (``bench/traffic/<traffic>.json``): the FL job a
-researcher submits. The run
+researcher submits. The configuration's own files choose the task: its
+``task`` key the data generator (``bench/data.make_task_data``), its
+``program`` key (``"module:function"``) the builder of the program side,
+and its reference module's hooks the reference's loss, eval and
+trained part (``bench/reference.py``). The run
 
 1. checks that JAX sees a TPU with as many chips as the cell asks for,
    and exits 3 without a result where it does not;
 2. set-up: draws the data and the initial weights from ``--seed``,
-   builds the engine through ``repro.engine.build_host_engine`` and runs
+   calls the configuration's builder, builds the engine on what it
+   returns through ``repro.engine.build_host_engine`` and runs
    one warm-up job of ``CHECKED_ROUNDS`` rounds, which compiles every
    program the window uses;
 3. the window: jobs (``FLEngine.run_sweep`` of ``rounds_per_job``
@@ -20,7 +25,10 @@ researcher submits. The run
    kept for the comparison: that job runs after another, as every
    window job does;
 4. reads the peak device memory, frees the engine, runs the plain
-   reference over those rounds and compares (``bench/compare.py``).
+   reference over those rounds and compares (``bench/compare.py``): a
+   run is correct when each number is within its cell's limit, every
+   checked round's global model was compared and no window round read
+   a non-finite value.
 
 ``--trace 0`` reports the end-to-end metrics: ``rounds_per_s`` (all
 rounds of the window over its length), ``round_ms_p95`` (the 95th
@@ -100,17 +108,29 @@ def _load_module(path: Path):
     return mod
 
 
-def load_cell(name: str) -> Cell:
-    wl = json.loads((BENCH / "workloads" / f"{name}.json").read_text())
+def load_cell(name: str, root: Path = BENCH) -> Cell:
+    """The cell ``name`` from the ``workloads``, ``traffic`` and
+    ``configs`` directories under ``root``."""
+    wl = json.loads((root / "workloads" / f"{name}.json").read_text())
     traffic = json.loads(
-        (BENCH / "traffic" / f"{wl['traffic']}.json").read_text())
-    cfg = json.loads((BENCH / "configs" / f"{wl['config']}.json").read_text())
-    model = _load_module(BENCH / "configs" / cfg["reference"])
+        (root / "traffic" / f"{wl['traffic']}.json").read_text())
+    cfg = json.loads((root / "configs" / f"{wl['config']}.json").read_text())
+    model = _load_module(root / "configs" / cfg["reference"])
     bench = json.loads((ROOT / "BENCHMARK.json").read_text())
     def mine(entries):
         return [m for m in entries if name in m.get("workloads", [name])]
     return Cell(name, wl, traffic, cfg, model, mine(bench["per_layer"]),
                 [m["name"] for m in mine(bench["end_to_end"])])
+
+
+def program_builder(cfg: dict):
+    """The configuration's ``program``, ``"module:function"``: called as
+    ``builder(cfg, weights, test)``, it returns the trained part of the
+    initial weights, the program's ``loss_fn(params, batch)`` and
+    ``eval_fn(params)``, and further keyword arguments of
+    ``repro.engine.build_host_engine``."""
+    module, _, fn = cfg["program"].partition(":")
+    return getattr(importlib.import_module(module), fn)
 
 
 # --------------------------------------------------------- the devices
@@ -152,30 +172,21 @@ def job_seed(seed: int, job: int) -> int:
 
 
 def make_inputs(cell: Cell, seed: int):
-    """Users' ``(x, y)`` arrays, the test split and the initial weights
-    (float32, made on the device in one call), all from ``seed``."""
+    """Users' data (dicts of arrays, one example a row), the test split
+    and the initial weights (float32, made on the device in one call),
+    all from ``seed``."""
     import jax
-    from bench.data import make_dataset, partition
-    cfg, tr = cell.config, cell.params
-    U, epu = tr["users"], tr["examples_per_user"]
-    (x, y), test = make_dataset(seed, cfg["input_shape"], cfg["n_classes"],
-                                U * epu, cfg["n_test"])
-    if cfg["input_layout"] == "flat":
-        x = x.reshape(len(x), -1)
-        test = (test[0].reshape(len(test[1]), -1), test[1])
-    parts = partition(x, y, U, tr["partition"], seed,
-                      tr.get("shards_per_user", 2))
-    users = [(x[i], y[i]) for i in parts]
-    del x, y
+    from bench.data import make_task_data
+    users, test = make_task_data(seed, cell.config, cell.params)
     key = jax.random.wrap_key_data(np.random.SeedSequence(
         [int(seed) & (2 ** 64 - 1), 1 << 20]).generate_state(2, np.uint32))
-    init = jax.jit(lambda k: cell.model.init(k, cfg))(key)
+    init = jax.jit(lambda k: cell.model.init(k, cell.config))(key)
     return users, test, init
 
 
 # ------------------------------------------------------------ engine
 class EvalRecorder:
-    """The program's accuracy eval, stamped with the host clock after
+    """The program's eval, stamped with the host clock after
     each call; the next ``capture_left`` calls also keep the evaluated
     global model (host copy) in ``captured``."""
 
@@ -190,26 +201,19 @@ class EvalRecorder:
         if self.capture_left > 0:
             self.captured.append(jax.device_get(params))
             self.capture_left -= 1
-        acc = self.prog_eval(params)
+        value = self.prog_eval(params)
         self.stamps.append(time.perf_counter())
-        return acc
+        return value
 
 
 def build_engine(cell: Cell, users, test, init, devices):
-    import jax
-    import jax.numpy as jnp
-    from repro.engine import (ExperimentSpec, build_host_engine,
-                              make_accuracy_eval)
-    from repro.models.paper_models import get_paper_model
-    tr, pm = cell.params, cell.config["program_model"]
-    _, apply_fn = get_paper_model(pm["name"], pm["dataset"])
-    n_classes = cell.config["n_classes"]
-
-    def loss_fn(params, batch):
-        logits = apply_fn(params, batch["x"])
-        oh = jax.nn.one_hot(batch["y"], n_classes)
-        return -jnp.mean(jnp.sum(oh * jax.nn.log_softmax(logits), -1))
-
+    """The engine on the configuration's program; returns ``(engine,
+    spec, recorder, trained)``, ``trained`` the part of ``init`` that
+    the engine trains."""
+    from repro.engine import ExperimentSpec, build_host_engine
+    tr = cell.params
+    trained, loss_fn, eval_fn, extra = program_builder(cell.config)(
+        cell.config, init, test)
     spec = ExperimentSpec(
         k_per_round=tr["k"], rounds=tr["rounds_per_job"], eval_every=1,
         strategy=tr["strategy"], cw_base=float(tr["cw_base"]),
@@ -224,11 +228,10 @@ def build_engine(cell: Cell, users, test, init, devices):
     if len(devices) > 1:        # a cell on 4 chips shards the cohort
         from repro.sharding.cohort import cohort_mesh
         mesh = cohort_mesh(devices)
-    recorder = EvalRecorder(make_accuracy_eval(apply_fn, *test))
-    engine = build_host_engine(
-        spec, init, loss_fn, [{"x": x, "y": y} for x, y in users],
-        recorder, mesh=mesh)
-    return engine, spec, recorder
+    recorder = EvalRecorder(eval_fn)
+    engine = build_host_engine(spec, trained, loss_fn, users, recorder,
+                               mesh=mesh, **extra)
+    return engine, spec, recorder, trained
 
 
 def run_job(engine, spec, recorder):
@@ -345,8 +348,11 @@ def run(args, *, cell: Cell = None, chip: bool = True) -> dict:
     tr = cell.params
 
     users, test, init = make_inputs(cell, args.seed)
-    engine, spec, recorder = build_engine(cell, users, test, init, devices)
+    engine, spec, recorder, trained = build_engine(cell, users, test, init,
+                                                   devices)
     init_host = jax.device_get(init)
+    trained_host = jax.device_get(trained)
+    del trained
 
     # set-up: the warm-up job compiles every program the window runs
     run_job(engine, replace(spec, seed=job_seed(args.seed, 0),
@@ -386,7 +392,7 @@ def run(args, *, cell: Cell = None, chip: bool = True) -> dict:
                 priorities=[np.asarray(p, np.float64)
                             for p in h.priorities[:n]],
                 accuracy=list(h.accuracy[:n]),
-                globals=[init_host] + recorder.captured)
+                globals=[trained_host] + recorder.captured)
             recorder.captured = []
         bad_rounds += sum(1 for v in h.train_loss + h.accuracy
                           if not math.isfinite(v))
@@ -416,7 +422,7 @@ def run(args, *, cell: Cell = None, chip: bool = True) -> dict:
             chips=chips, peak=peak, cell=cell,
             parameters=int(cell.model.parameter_count(cell.config)),
             flops_per_example=int(
-                cell.model.train_flops_per_example(cell.config)),
+                cell.model.train_flops_per_example(cell.config, tr)),
             compiles=counter.count)
         metrics = {}
         for m in cell.metrics:
@@ -460,8 +466,8 @@ def run(args, *, cell: Cell = None, chip: bool = True) -> dict:
     del engine, recorder
     gc.collect()
     from bench import compare, reference
-    ref = reference.run_job(cell.model.apply, init_host, users, test, tr,
-                            checked_seed, CHECKED_ROUNDS, probe=prog)
+    ref = reference.run_job(cell.model, cell.config, init_host, users, test,
+                            tr, checked_seed, CHECKED_ROUNDS, probe=prog)
     cmp = compare.compare(prog, ref)
     limits = cell.workload["limits"]
     # JSON has no inf or NaN: a number that could not be read, or read
@@ -469,14 +475,19 @@ def run(args, *, cell: Cell = None, chip: bool = True) -> dict:
     checks = {k: {"value": (v if math.isfinite(v) else None),
                   "limit": limits[k]}
               for k, v in cmp["checks"].items()}
+    # every checked round's global model is compared, or the run fails
+    missed = CHECKED_ROUNDS - cmp["rounds_compared"]
+    checks["rounds_not_compared"] = {"value": missed, "limit": 0}
     result["correct"] = (compare.judge(cmp["checks"], limits)
-                         and bad_rounds == 0)
+                         and missed == 0 and bad_rounds == 0)
+    result["rounds_followed"] = cmp["rounds_followed"]
     result["checks"] = checks
-    for k, v in cmp["checks"].items():
-        print(f"check {k}: {v!r} (limit {limits[k]!r})", file=sys.stderr)
-    print(f"check rounds_compared: {cmp['rounds_compared']} of "
-          f"{CHECKED_ROUNDS}; non-finite rounds in window: {bad_rounds}",
-          file=sys.stderr)
+    print(f"rounds followed on the program's priorities: "
+          f"{cmp['rounds_followed']} of {CHECKED_ROUNDS}; non-finite "
+          f"rounds in window: {bad_rounds} (limit 0)", file=sys.stderr)
+    for k, v in {**cmp["checks"], "rounds_not_compared": missed}.items():
+        print(f"check {k}: {v!r} (limit {checks[k]['limit']!r})",
+              file=sys.stderr)
     return result
 
 

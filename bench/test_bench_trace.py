@@ -79,7 +79,7 @@ def _view(summary, rounds, cell="paper-mlp"):
         trace=summary, rounds=rounds, window_s=summary.window_ns * 1e-9,
         chips=1, peak=run.peak_of("TPU v5 lite"), cell=c,
         parameters=c.model.parameter_count(c.config),
-        flops_per_example=c.model.train_flops_per_example(c.config),
+        flops_per_example=c.model.train_flops_per_example(c.config, c.params),
         compiles=0)
 
 
@@ -87,6 +87,7 @@ def test_readers_on_a_hand_made_window():
     v = _view(tracing.summarize(_window()), rounds=1)
     assert run.read_metric("device.idle_share", v) == pytest.approx(72.0)
     assert run.read_metric("train.device_ms", v) == pytest.approx(3e-4)
+    assert run.read_metric("merge.device_ms", v) == pytest.approx(2e-5)
     assert run.read_metric("batches.host_ms", v) == pytest.approx(3e-4)
     # (rows + 1) * P * 4 bytes at 819 GB/s over 60 ns
     want = 100 * 11 * 159010 * 4 / 819e9 / 60e-9
@@ -107,9 +108,9 @@ def test_recorded_window():
     assert 0 < s.busy_ns[DEV] == busy <= s.window_ns
     assert s.module_ns(r"^jit_sweep_round$") > 0
     v = _view(s, rounds=1)
-    for name in ("device.idle_share", "train.device_ms", "batches.host_ms",
-                 "selection.host_ms", "delta_norm_roofline",
-                 "gather_combine_roofline"):
+    for name in ("device.idle_share", "train.device_ms", "merge.device_ms",
+                 "batches.host_ms", "selection.host_ms",
+                 "delta_norm_roofline", "gather_combine_roofline"):
         value = run.read_metric(name, v)
         assert value is not None and value > 0, name
     for name in ("delta_norm_roofline", "gather_combine_roofline",
